@@ -1,55 +1,36 @@
-"""JAX version-compatibility layer.
+"""The JAX API surface this repo builds on, in one place.
 
-The reproduction must run on whatever JAX the edge device ships (the
-portability constraint of arXiv 2406.03777 / 2311.14030): API surfaces
-that moved or changed signature across JAX releases are feature-detected
-*once* here and exposed as a single stable interface. Nothing outside
-this module may import ``AxisType``, pass version-gated ``make_mesh``
-kwargs, or import ``shard_map`` from its (moving) home.
+The repo runs on one installation: JAX 0.9.0 (jaxlib 0.9.0, libtpu
+0.0.34), pinned in pyproject.toml. APIs whose spelling has moved between
+JAX releases are called here and nowhere else, so a future upgrade
+touches one file. Nothing outside this module may import ``AxisType``,
+pass ``axis_types=`` / ``check_vma=``, or import ``shard_map``.
 
-Supported surface (tested on JAX 0.4.30–0.4.x; forward-compatible paths
-for 0.5+/0.6+ are exercised opportunistically by feature detection):
-
-* ``jax_version()`` / ``jax_at_least(version)`` — version guards.
-* ``make_mesh(shape, axes)`` — ``jax.make_mesh`` with ``axis_types``
-  when the installed JAX has :class:`AxisType`, without it on 0.4.x, and
-  a manual ``Mesh(mesh_utils.create_device_mesh(...))`` on versions that
-  predate ``jax.make_mesh`` entirely.
-* ``abstract_mesh(shape, axes)`` — :class:`AbstractMesh` across its two
-  constructor signatures (pairs-tuple on 0.4.x, split args later).
-* ``shard_map(f, mesh, in_specs, out_specs, check_rep=...)`` — resolves
-  ``jax.shard_map`` vs ``jax.experimental.shard_map.shard_map`` and maps
-  the replication-check kwarg (``check_rep`` → ``check_vma`` rename).
-* ``tree_map`` / ``tree_leaves`` / ``tree_structure`` /
-  ``tree_map_with_path`` — ``jax.tree`` on versions that have it,
-  ``jax.tree_util`` otherwise.
-* ``ambient_mesh()`` — the mesh from an enclosing ``with mesh:`` /
-  ``set_mesh`` context, across the abstract-mesh and thread-resources
-  eras.
-* ``force_host_device_count(n)`` — the ``XLA_FLAGS`` dance for faked
+* ``make_mesh(shape, axes)`` — ``jax.make_mesh`` with ``Auto`` axes.
+* ``abstract_mesh(shape, axes)`` — :class:`jax.sharding.AbstractMesh`.
+* ``shard_map(f, mesh, in_specs, out_specs, check_rep=...)`` —
+  ``jax.shard_map``; ``check_rep`` is its ``check_vma``.
+* ``tree_map`` / ``tree_map_with_path`` — the pytree helpers.
+* ``ambient_mesh()`` — the mesh set by an enclosing ``jax.set_mesh``.
+* ``force_host_device_count(n)`` — the ``XLA_FLAGS`` knob for faked
   host devices (must run before the first backend initialisation).
-* ``enable_compilation_cache(dir)`` — persistent compile cache knobs.
+* ``default_cache_dir()`` / ``enable_compilation_cache()`` — JAX's
+  persistent compilation cache.
 """
 
 from __future__ import annotations
 
-import functools
-import inspect
 import os
 import re
-from typing import Optional, Sequence, Union
+from typing import Optional
 
 import jax
 
 __all__ = [
-    "jax_version",
-    "jax_at_least",
     "make_mesh",
     "abstract_mesh",
     "shard_map",
     "tree_map",
-    "tree_leaves",
-    "tree_structure",
     "tree_map_with_path",
     "ambient_mesh",
     "force_host_device_count",
@@ -59,152 +40,45 @@ __all__ = [
 
 
 # ---------------------------------------------------------------------------
-# Version guards
-# ---------------------------------------------------------------------------
-
-
-@functools.lru_cache(maxsize=None)
-def jax_version() -> tuple:
-    """Installed JAX version as a comparable int tuple (dev tags dropped)."""
-    parts = []
-    for p in jax.__version__.split("."):
-        m = re.match(r"\d+", p)
-        if not m:
-            break
-        parts.append(int(m.group()))
-    return tuple(parts) if parts else (0,)
-
-
-def jax_at_least(version: Union[str, Sequence[int]]) -> bool:
-    """True iff the installed JAX is >= ``version`` ("0.5", (0, 5, 0), ...)."""
-    if isinstance(version, str):
-        want = tuple(int(x) for x in version.split("."))
-    else:
-        want = tuple(int(x) for x in version)
-    return jax_version() >= want
-
-
-# ---------------------------------------------------------------------------
-# Mesh construction
+# Meshes
 # ---------------------------------------------------------------------------
 
 
 def make_mesh(shape, axes, *, devices=None):
-    """Device mesh with ``Auto`` axis semantics on every installed JAX.
-
-    Newest JAX: ``jax.make_mesh(..., axis_types=(AxisType.Auto, ...))``;
-    0.4.35–0.4.x: ``jax.make_mesh`` without ``axis_types`` (Auto is the
-    only behaviour); older: explicit ``Mesh`` over ``create_device_mesh``.
-    """
-    shape, axes = tuple(shape), tuple(axes)
-    maker = getattr(jax, "make_mesh", None)
-    if maker is not None:
-        axis_type = getattr(jax.sharding, "AxisType", None)
-        if axis_type is not None:
-            try:
-                return maker(
-                    shape, axes, devices=devices,
-                    axis_types=(axis_type.Auto,) * len(axes),
-                )
-            except TypeError:  # has AxisType but an older make_mesh
-                pass
-        return maker(shape, axes, devices=devices)
-    from jax.experimental import mesh_utils
-
-    devs = mesh_utils.create_device_mesh(shape, devices=devices)
-    return jax.sharding.Mesh(devs, axes)
+    """Device mesh whose axes all have ``Auto`` sharding semantics."""
+    axes = tuple(axes)
+    return jax.make_mesh(
+        tuple(shape), axes, devices=devices,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+    )
 
 
 def abstract_mesh(shape, axes):
-    """:class:`jax.sharding.AbstractMesh` across constructor signatures."""
-    from jax.sharding import AbstractMesh
-
-    shape, axes = tuple(shape), tuple(axes)
-    try:
-        return AbstractMesh(shape, axes)  # 0.5+ split signature
-    except TypeError:
-        return AbstractMesh(tuple(zip(axes, shape)))  # 0.4.x pairs tuple
-
-
-# ---------------------------------------------------------------------------
-# shard_map
-# ---------------------------------------------------------------------------
-
-
-@functools.lru_cache(maxsize=None)
-def _resolve_shard_map():
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn  # moved in 0.6
-    try:
-        params = frozenset(inspect.signature(fn).parameters)
-    except (TypeError, ValueError):  # C-accelerated / no signature
-        params = frozenset()
-    return fn, params
+    """:class:`jax.sharding.AbstractMesh` of the given shape."""
+    return jax.sharding.AbstractMesh(tuple(shape), tuple(axes))
 
 
 def shard_map(f, mesh, in_specs, out_specs, check_rep: Optional[bool] = None):
-    """``shard_map`` with the replication check spelled one way.
-
-    ``check_rep`` (old spelling) maps onto ``check_vma`` on JAX versions
-    that renamed it; ``None`` keeps the installed default.
-    """
-    fn, params = _resolve_shard_map()
-    kwargs = {}
-    if check_rep is not None:
-        if "check_vma" in params:
-            kwargs["check_vma"] = check_rep
-        elif "check_rep" in params:
-            kwargs["check_rep"] = check_rep
-    return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
-
-
-# ---------------------------------------------------------------------------
-# Pytree helpers (jax.tree arrived in 0.4.25)
-# ---------------------------------------------------------------------------
-
-if hasattr(jax, "tree") and hasattr(jax.tree, "map"):
-    tree_map = jax.tree.map
-    tree_leaves = jax.tree.leaves
-    tree_structure = jax.tree.structure
-else:  # pragma: no cover - exercised only on very old JAX
-    tree_map = jax.tree_util.tree_map
-    tree_leaves = jax.tree_util.tree_leaves
-    tree_structure = jax.tree_util.tree_structure
-
-tree_map_with_path = jax.tree_util.tree_map_with_path
-
-
-# ---------------------------------------------------------------------------
-# Ambient mesh discovery
-# ---------------------------------------------------------------------------
+    """``jax.shard_map``; ``check_rep`` sets its replication check
+    (``check_vma``), ``None`` keeps JAX's default."""
+    kwargs = {} if check_rep is None else {"check_vma": check_rep}
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
 
 
 def ambient_mesh():
-    """The mesh from the enclosing ``with mesh:`` / ``set_mesh`` context.
+    """The mesh set by an enclosing ``jax.set_mesh``; ``None`` when no
+    mesh is set — callers treat that as the single-device regime."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
-    Tries the modern abstract-mesh context first (``set_mesh`` era), then
-    the thread-resources physical mesh (``with mesh:`` era). ``None``
-    when no mesh is active — callers treat that as the single-device
-    CPU-test regime.
-    """
-    getter = getattr(jax.sharding, "get_abstract_mesh", None)
-    if getter is not None:
-        try:
-            m = getter()
-            if m is not None and not m.empty:
-                return m
-        except Exception:
-            pass
-    try:
-        from jax._src import mesh as mesh_lib
 
-        m = mesh_lib.thread_resources.env.physical_mesh
-        if m is not None and not m.empty:
-            return m
-    except Exception:
-        pass
-    return None
+# ---------------------------------------------------------------------------
+# Pytree helpers
+# ---------------------------------------------------------------------------
+
+tree_map = jax.tree.map
+tree_map_with_path = jax.tree_util.tree_map_with_path
 
 
 # ---------------------------------------------------------------------------
@@ -225,42 +99,29 @@ def force_host_device_count(n: int) -> None:
     ).strip()
 
 
+_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+
 def default_cache_dir() -> str:
-    """The repo-wide compile-cache location (one policy for the test
-    harness, the benchmark runner, and CI's actions/cache path).
-
-    A user-set ``JAX_COMPILATION_CACHE_DIR`` is honored so the config
-    update in :func:`enable_compilation_cache` never diverges from the
-    env var that subprocesses inherit.
-    """
-    return (
-        os.environ.get("REPRO_JAX_CACHE_DIR")
-        or os.environ.get("JAX_COMPILATION_CACHE_DIR")
-        or os.path.join(os.path.expanduser("~"), ".cache", "repro_jax_cache")
-    )
+    """Where the persistent compilation cache lives: the directory
+    ``JAX_COMPILATION_CACHE_DIR`` names when it is set, else
+    ``<repo>/.jax_cache`` (git-ignored). A fixed path matters: the
+    cache key includes it, so a moving directory never hits."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
+        _REPO_ROOT, ".jax_cache")
 
 
-def enable_compilation_cache(cache_dir: Optional[str] = None) -> bool:
-    """Point JAX's persistent compilation cache at ``cache_dir``
-    (default: :func:`default_cache_dir`).
+def enable_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache in
+    :func:`default_cache_dir` and return that directory.
 
     Thresholds are dropped to zero so even the tiny CPU-test programs
-    cache (the default min-compile-time gate skips them). Returns False
-    when the installed JAX predates the config knobs.
+    cache (the default min-compile-time gate skips them). Raises if the
+    directory cannot be created.
     """
-    if cache_dir is None:
-        cache_dir = default_cache_dir()
-    try:
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-    except Exception:
-        return False
-    for knob, val in (
-        ("jax_persistent_cache_min_compile_time_secs", 0.0),
-        ("jax_persistent_cache_min_entry_size_bytes", -1),
-    ):
-        try:
-            jax.config.update(knob, val)
-        except Exception:
-            pass
-    return True
+    cache_dir = default_cache_dir()
+    os.makedirs(cache_dir, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return cache_dir

@@ -4,10 +4,13 @@ Each kernel has a pure-jnp oracle in :mod:`repro.kernels.ref` and is
 allclose-pinned to it in ``tests/test_kernels.py`` /
 ``tests/test_cached_step.py``. Shared conventions:
 
-* **interpret escape hatch** — every kernel takes ``interpret=``; pass
-  ``True`` off-TPU (CI does, everywhere) to run the kernel body through
-  the Pallas interpreter: bit-accurate, not fast. The ``ops``/
-  ``cached_step`` wrappers auto-select on ``jax.default_backend()``.
+* **interpret mode** — every kernel takes ``interpret=``; ``True`` runs
+  the kernel body through the Pallas interpreter (bit-accurate, not
+  fast), which is how the CPU tests run them. ``None`` (the
+  ``cached_step``/``paged_attention``/OpSet default) compiles for the TPU
+  and interprets only when JAX's default backend is not a TPU — e.g.
+  under ``JAX_PLATFORMS=cpu``. ``tests/test_tpu_compile.py`` compiles
+  each main-path kernel for a described v5e chip.
 * **ragged shapes** — public entry points either pad-and-slice
   non-divisible dims (``adapter_fuse``, everything in ``cached_step``)
   or clamp block sizes and assert divisibility (``quant_matmul``,
@@ -26,6 +29,6 @@ Modules:
   weights (paper §IV-D).
 * ``adapter_fuse`` — single λ-mix combine for f32 taps.
 * ``flash_attention`` — causal/windowed/soft-capped attention.
-* ``ops`` — jit'd public wrappers with CPU (ref) fallbacks.
+* ``paged_attention`` — paged-KV decode attention (the serving core).
 * ``ref`` — the jnp oracles.
 """

@@ -106,6 +106,24 @@ def entry_to_f32(x, orig_last: int) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
+def _scale_col(s, col):
+    """Column ``col`` (traced) of a (rows, nb) scale block, as (rows, 1):
+    a masked lane sum, exact because it selects a single lane."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    return jnp.sum(jnp.where(lane == col, s, 0.0), axis=1, keepdims=True)
+
+
+def _dequant_tile(q, s, first, qblock):
+    """f32 tile of an int8 entry: q (rows, cols) int8 and s, the rows'
+    whole (rows, nb) scale block; the tile's quantization blocks are
+    ``first, first + 1, ...`` (``first`` may be traced)."""
+    return jnp.concatenate([
+        q[:, c * qblock:(c + 1) * qblock].astype(jnp.float32)
+        * _scale_col(s, first + c)
+        for c in range(q.shape[1] // qblock)
+    ], axis=1)
+
+
 def _mix_fwd_kernel(q_ref, s_ref, w_ref, a_ref, lam_ref, o_ref, bw_ref,
                     acc_ref, *, n_k: int, qblock: int):
     """One (bt, bj) output tile; K innermost. s_ref is None for float
@@ -119,13 +137,8 @@ def _mix_fwd_kernel(q_ref, s_ref, w_ref, a_ref, lam_ref, o_ref, bw_ref,
     if s_ref is None:
         x = q_ref[...].astype(jnp.float32)
     else:
-        q = q_ref[...]
-        s = s_ref[...]
-        bt_, bk_ = q.shape
-        x = (
-            q.astype(jnp.float32).reshape(bt_, bk_ // qblock, qblock)
-            * s[..., None]
-        ).reshape(bt_, bk_)
+        bk_ = q_ref.shape[1]
+        x = _dequant_tile(q_ref[...], s_ref[...], k * (bk_ // qblock), qblock)
     acc_ref[...] += jax.lax.dot_general(
         x, w_ref[...].astype(jnp.float32), (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -165,8 +178,11 @@ def _mix_fwd_impl(q, scale, w, a, lam, bt, bj, bk, interpret):
     args = [q]
     if scale is not None:
         scale = _pad_to(_pad_to(scale, 0, Tp), 1, Kp // qblock)
+        # the rows' whole scale block: a (bt, bk // qblock) slice would
+        # break Mosaic's (8, 128)-or-full-dim rule; the kernel picks the
+        # tile's columns out of it
         in_specs.append(
-            pl.BlockSpec((bt, bk // qblock), lambda i, j, k: (i, k))
+            pl.BlockSpec((bt, Kp // qblock), lambda i, j, k: (i, 0))
         )
         args.append(scale)
     in_specs += [
@@ -213,13 +229,9 @@ def _mix_dw_kernel(q_ref, s_ref, g_ref, lam_ref, dw_ref, acc_ref,
     if s_ref is None:
         x = q_ref[...].astype(jnp.float32)
     else:
-        q = q_ref[...]
-        s = s_ref[...]
-        bt_, bi_ = q.shape
-        x = (
-            q.astype(jnp.float32).reshape(bt_, bi_ // qblock, qblock)
-            * s[..., None]
-        ).reshape(bt_, bi_)
+        bi_ = q_ref.shape[1]
+        x = _dequant_tile(
+            q_ref[...], s_ref[...], pl.program_id(0) * (bi_ // qblock), qblock)
     acc_ref[...] += jax.lax.dot_general(
         x, g_ref[...].astype(jnp.float32), (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -251,8 +263,8 @@ def _mix_dw_impl(q, scale, g, lam, d_out, out_dtype, bi, bj, bkt, interpret):
     args = [q]
     if scale is not None:
         scale = _pad_to(_pad_to(scale, 0, Tp), 1, Dp // qblock)
-        in_specs.append(
-            pl.BlockSpec((bkt, bi // qblock), lambda i, j, k: (k, i))
+        in_specs.append(  # whole scale rows, as in the forward
+            pl.BlockSpec((bkt, Dp // qblock), lambda i, j, k: (k, 0))
         )
         args.append(scale)
     in_specs += [

@@ -93,8 +93,7 @@ def flash_attention_tpu(
 
     Block sizes ``bq/bk`` tile (Sq, Sk); they are clamped to the dims
     and then **asserted** to divide them (no pad-and-slice here — the
-    serving shapes are powers of two; ``ops.flash_attention`` is the
-    auto-selecting wrapper). Softmax state is carried in f32 VMEM
+    pallas OpSet pads S to a block multiple). Softmax state is carried in f32 VMEM
     scratch across K steps. ``interpret=True`` runs the Pallas
     interpreter off-TPU (bit-accurate, slow — the CI path).
     """

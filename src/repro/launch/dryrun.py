@@ -50,7 +50,7 @@ def run_case(arch: str, shape_name: str, *, multi_pod: bool, technique: str,
     case = build_case(arch, shape_name, mesh, technique=technique,
                       quant_bits=quant_bits, kv_quant=kv_quant,
                       dtype={"f32": jnp.float32, "bf16": jnp.bfloat16}[dtype])
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = case.lower()
         t_lower = time.perf_counter() - t0
         compiled = lowered.compile()

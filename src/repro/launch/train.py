@@ -81,6 +81,7 @@ from __future__ import annotations
 
 import argparse
 
+from repro import compat
 from repro.runtime import ConsoleHook, EdgeSession, RunSpec, RunSpecError
 
 _EPILOG = """\
@@ -143,6 +144,8 @@ def main() -> None:
                          "blockwise-CE cached step (interpret mode off-TPU)")
     args = ap.parse_args()
 
+    # config only — the backend (and its device count) is still unset
+    compat.enable_compilation_cache()
     try:
         spec = RunSpec.from_args(args)
         EdgeSession(spec, log=print).run(hooks=(ConsoleHook(),))

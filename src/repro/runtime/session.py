@@ -246,7 +246,10 @@ class EdgeSession:
         # ---- model: backbone (frozen, maybe quantized) + adapter --------
         bp = bb.init_backbone(jax.random.PRNGKey(spec.seed), cfg)
         if spec.quant:
-            bq = quantize_tree(bp, bits=spec.quant)
+            # one jitted program: eagerly, each op would hold a whole f32
+            # temporary of the largest stacked leaf beside the f32 tree
+            # (7 GiB at internlm2-1.8b widths)
+            bq = jax.jit(functools.partial(quantize_tree, bits=spec.quant))(bp)
             log(f"backbone quantized INT{spec.quant}: "
                 f"{tree_storage_bytes(bp)/2**20:.1f} MB → "
                 f"{tree_storage_bytes(bq)/2**20:.1f} MB")
@@ -293,6 +296,12 @@ class EdgeSession:
                 self.mesh = make_edge_mesh(exec_dp, exec_stages)
                 log(f"mesh: hybrid dp={exec_dp}×pp={exec_stages} on "
                     f"{total} devices, {n_micro} micro-batches")
+            # resident on every mesh device: left on the default device,
+            # the frozen tree would be copied out again on every step
+            rep = jax.sharding.NamedSharding(
+                self.mesh, jax.sharding.PartitionSpec())
+            self.backbone, self.adapter, self.opt = jax.device_put(
+                (self.backbone, self.adapter, self.opt), rep)
 
         # ---- data + activation cache ------------------------------------
         n_seq = spec.steps_per_epoch * spec.batch
@@ -433,9 +442,6 @@ class EdgeSession:
         is consumed here)."""
         import time
 
-        import jax
-        import jax.numpy as jnp
-
         if not self._opened:
             raise RuntimeError("EdgeSession.step() before open() — use "
                                "`with EdgeSession(spec) as s:` or s.open()")
@@ -452,18 +458,50 @@ class EdgeSession:
                                      orig_last=self.cfg.d_model)
             cache_hit = False
         else:
-            b0, taps, bf = (jax.tree.map(jnp.asarray, h) for h in hit)
-            cached = {"b0": b0, "taps": taps, "b_final": bf,
-                      "labels": batch["labels"]}
-            if self._stepN is None:
-                self._stepN = self._build_cached_step(cached)
-            loss, self.adapter, self.opt = self._stepN(
+            cached = self._cached_inputs(hit, batch)
+            loss, self.adapter, self.opt = self._cached_step(cached)(
                 self.backbone, self.adapter, self.opt, cached)
             cache_hit = True
         loss = float(loss)
         return StepEvent(
             epoch=epoch, index=index, loss=loss, cache_hit=cache_hit,
             mode=self.mode(cache_hit), wall_s=time.perf_counter() - t0)
+
+    @staticmethod
+    def _cached_inputs(hit, batch: dict) -> dict:
+        import jax
+        import jax.numpy as jnp
+
+        b0, taps, bf = (jax.tree.map(jnp.asarray, h) for h in hit)
+        return {"b0": b0, "taps": taps, "b_final": bf,
+                "labels": batch["labels"]}
+
+    def _cached_step(self, cached: dict):
+        if self._stepN is None:
+            self._stepN = self._build_cached_step(cached)
+        return self._stepN
+
+    def lower_step(self, batch: dict):
+        """Lower, without running, the step :meth:`step` would run for
+        ``batch`` (one :meth:`DataPipeline.epoch` item): the cached step
+        when the cache holds the whole batch, else the epoch-1 step. The
+        result's ``as_text()`` is the program the device gets — e.g.
+        whether the Pallas kernels are there as ``tpu_custom_call``s or
+        were interpreted — and ``compile()`` times its compilation."""
+        if not self._opened:
+            raise RuntimeError("lower_step() needs an open()ed session")
+        batch = dict(batch)
+        ids = batch.pop("seq_ids")
+        hit = None
+        if self.spec.use_cache:
+            hit = self.cache.get_batch(ids, with_final=True, dtype=None,
+                                       compressed=self._use_pallas)
+        if hit is None:
+            return self._step1.lower(self.backbone, self.adapter, self.opt,
+                                     batch)
+        cached = self._cached_inputs(hit, batch)
+        return self._cached_step(cached).lower(
+            self.backbone, self.adapter, self.opt, cached)
 
     def mode(self, cache_hit: bool) -> str:
         """The run-mode label the trainer has always reported."""

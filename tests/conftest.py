@@ -1,13 +1,13 @@
 """Shared test harness.
 
-* Pins tests to the single real CPU device (only the dry-run entry point
-  fakes 512 devices, in its own process).
-* Enables JAX's persistent compilation cache — the suite otherwise
-  burns minutes recompiling identical tiny programs on every run. The
-  in-process enablement is the ``compat.enable_compilation_cache()``
-  config call below; the env vars exist so subprocess tests
-  (test_pipeline) inherit the same cache. Override the location with
-  ``REPRO_JAX_CACHE_DIR``.
+* Pins tests to the CPU (``JAX_PLATFORMS=cpu``): Pallas kernels run in
+  interpret mode. ``test_tpu_compile.py`` compiles the main-path kernels
+  for a described TPU v5e without running them.
+* Enables JAX's persistent compilation cache in
+  ``compat.default_cache_dir()`` — ``JAX_COMPILATION_CACHE_DIR`` when
+  set, else ``<repo>/.jax_cache`` — so reruns skip recompiling identical
+  tiny programs. The directory and thresholds travel as env vars too,
+  so subprocess tests (test_pipeline) cache in the same place.
 * Session-scoped tiny-config/params/batch fixtures shared across
   modules, so each module stops re-initialising the same reduced model.
 """
@@ -26,7 +26,7 @@ import pytest  # noqa: E402
 
 from repro import compat  # noqa: E402
 
-# env (not jax.config) so the test subprocesses pick the cache up too
+# env (as well as jax.config) so test subprocesses use the same cache
 os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", compat.default_cache_dir())
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "-1")

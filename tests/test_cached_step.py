@@ -158,17 +158,15 @@ def test_pallas_cached_step_matches_ref_per_policy(
     loss_ref, ap_ref, _ = step_ref(bp, ap, opt, cached_c)
     loss_pal, ap_pal, _ = step_pal(bp, ap, opt, cached_c)
     # ref on compressed entries == ref on host-decompressed entries
-    # (the handoff changes where dequant runs, not its result)
+    # (the handoff changes where dequant runs, not its result): both
+    # dequantize the same payload, so only summation order differs
     loss_ref_d, _, _ = step_ref(bp, ap, opt, cached_d)
     assert abs(float(loss_ref) - float(loss_ref_d)) < 1e-5
 
+    # f32 loss near 6 (ln vocab) from two summation orders: a few ulps
     assert abs(float(loss_ref) - float(loss_pal)) < 2e-5
-    for a, b in zip(jax.tree.leaves(ap_ref), jax.tree.leaves(ap_pal)):
-        d = float(jnp.max(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32))))
-        assert d < 5e-5, d
 
-    # gradient-level equivalence (post-update params can mask per-leaf
-    # differences behind AdamW's eps)
+    # gradient-level equivalence — the primary check
     from repro.kernels.cached_step import cached_loss_parts
 
     B, S = batch["labels"].shape
@@ -185,9 +183,25 @@ def test_pallas_cached_step_matches_ref_per_policy(
 
     g_ref, g_pal = grads("ref"), grads("pallas")
     gmax = max(float(jnp.max(jnp.abs(g))) for g in jax.tree.leaves(g_ref))
+    # 1e-4 of the largest grad: f32 accumulation-order noise through the
+    # adapter's blocks, far below any real kernel error
     for a, b in zip(jax.tree.leaves(g_ref), jax.tree.leaves(g_pal)):
         d = float(jnp.max(jnp.abs(a - b)))
         assert d <= 1e-4 * max(1.0, gmax), (d, gmax)
+
+    # post-update params. AdamW's first step moves each weight by about
+    # lr · g / (|g| + eps): for |g| near eps = 1e-8 that ratio follows
+    # the rounding noise of g itself (under the int8 policy, ref and
+    # pallas grads of -4.4e-10 and -7.1e-10 moved one down-projection
+    # weight 2.2e-4 apart), so weights whose ref
+    # grad is below 100·eps are left to the gradient check above. Above
+    # it, the update is smooth in g and the two steps agree to 5e-5.
+    for a, b, g in zip(jax.tree.leaves(ap_ref), jax.tree.leaves(ap_pal),
+                       jax.tree.leaves(g_ref)):
+        live = jnp.abs(g) >= 1e-6
+        d = float(jnp.max(jnp.where(
+            live, jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32)), 0.0)))
+        assert d < 5e-5, d
 
 
 def test_prefetcher_compressed_handoff(tiny_cfg, tiny_backbone, tiny_adapter, tiny_batch):
