@@ -56,6 +56,7 @@ import jax
 import jax.numpy as jnp
 import ml_dtypes
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.quantization import QTensor, dequantize, quantize
 
@@ -182,6 +183,13 @@ def _raw_part(ct: _CTensor):
     if ct.policy == "int8":
         return {"q": ct.data, "scale": ct.scale}
     return ct.data
+
+
+def _part_nbytes(part) -> int:
+    """Bytes of one storage-form part (an array or a q/scale dict)."""
+    if isinstance(part, dict):
+        return sum(a.nbytes for a in part.values())
+    return part.nbytes
 
 
 def _stack_parts(parts, axis: int):
@@ -433,18 +441,30 @@ class ActivationCache:
         Compression runs once on the whole batch array and per-sequence
         entries are sliced (with copies) out of the result — block-wise
         quantization along the last axis makes the payloads bit-identical
-        to per-sequence compression at 1/B the dispatch overhead."""
-        cb0 = _compress(b0, self.compress, orig_last=orig_last)
-        ctaps = _compress(taps, self.compress, orig_last=orig_last)
-        cbf = None if b_final is None else _compress(b_final, self.compress, orig_last=orig_last)
-        for i, k in enumerate(keys):
-            entry = CacheEntry(
-                _ct_index(cb0, i),
-                _ct_index(ctaps, (slice(None), i)),
-                None if cbf is None else _ct_index(cbf, i),
-            )
-            with self._lock:
-                self._put_entry(int(k), entry)
+        to per-sequence compression at 1/B the dispatch overhead.
+
+        Host spans: ``pac.cache.put_batch`` holds ``pac.cache.fetch``
+        (the device→host copy, and compression where the parts are not
+        in storage form yet) and ``pac.cache.store`` (slicing and
+        storing the entries, eviction and spill included); the fetch
+        carries ``nbytes``, the stored batch's bytes."""
+        with TraceAnnotation("pac.cache.put_batch"):
+            with TraceAnnotation("pac.cache.fetch") as fetch_span:
+                cb0 = _compress(b0, self.compress, orig_last=orig_last)
+                ctaps = _compress(taps, self.compress, orig_last=orig_last)
+                cbf = None if b_final is None else _compress(
+                    b_final, self.compress, orig_last=orig_last)
+                fetch_span.set_metadata(
+                    nbytes=cb0.nbytes + ctaps.nbytes + (0 if cbf is None else cbf.nbytes))
+            with TraceAnnotation("pac.cache.store"):
+                for i, k in enumerate(keys):
+                    entry = CacheEntry(
+                        _ct_index(cb0, i),
+                        _ct_index(ctaps, (slice(None), i)),
+                        None if cbf is None else _ct_index(cbf, i),
+                    )
+                    with self._lock:
+                        self._put_entry(int(k), entry)
 
     def get_batch(self, keys, with_final: bool = False, dtype=np.float32,
                   compressed: bool = False):
@@ -632,6 +652,11 @@ class CachePrefetcher:
     drain the queue so a blocked ``put`` unblocks, join the thread). A
     leaked worker would otherwise keep device buffers alive through its
     queued ``device_put`` results until process exit.
+
+    Host spans: ``pac.prefetch.wait`` on the consumer's thread around the
+    blocking queue get (``n``: the batch's ordinal, 0 for the first);
+    ``pac.prefetch.load`` and ``pac.prefetch.device_put`` on the worker
+    (the latter with ``nbytes``, the batch's storage-form bytes).
     """
 
     _DONE = object()
@@ -657,6 +682,7 @@ class CachePrefetcher:
         self._err: Optional[BaseException] = None
         self._stop = threading.Event()
         self._done = False    # consumer saw the _DONE sentinel
+        self._n_taken = 0     # items the consumer has taken
         self._closed = False  # close() ran — iteration must fail fast
         self._thread = threading.Thread(
             target=self._worker, name="activation-cache-prefetch", daemon=True
@@ -668,14 +694,18 @@ class CachePrefetcher:
             for keys in self._key_batches:
                 if self._stop.is_set():
                     break
-                got = self._cache.get_batch(
-                    keys, with_final=self._with_final, dtype=self._dtype,
-                    compressed=self._compressed,
-                )
+                with TraceAnnotation("pac.prefetch.load"):
+                    got = self._cache.get_batch(
+                        keys, with_final=self._with_final, dtype=self._dtype,
+                        compressed=self._compressed,
+                    )
                 if got is not None and self._to_device:
                     # device_put handles the storage-form pytrees too
-                    # ({"q","scale"} dicts ship at integer width)
-                    got = tuple(jax.device_put(g) for g in got)
+                    # ({"q","scale"} dicts ship at integer width); the
+                    # span covers the enqueue, not the copy's end
+                    nbytes = sum(_part_nbytes(g) for g in got)
+                    with TraceAnnotation("pac.prefetch.device_put", nbytes=nbytes):
+                        got = tuple(jax.device_put(g) for g in got)
                 self._q.put(got)
         except BaseException as e:  # surfaced on the consumer side
             self._err = e
@@ -694,7 +724,10 @@ class CachePrefetcher:
             raise RuntimeError(
                 "CachePrefetcher iterated after close(); open a new "
                 "prefetcher over the remaining key batches")
-        item = self._q.get()
+        # n: this batch's ordinal in the prefetcher (0: an epoch's first)
+        with TraceAnnotation("pac.prefetch.wait", n=self._n_taken):
+            item = self._q.get()
+        self._n_taken += 1
         if item is self._DONE:
             self._done = True
             if self._err is not None:

@@ -70,6 +70,15 @@ def _pad_to(x, axis: int, target: int):
     return jnp.pad(x, pads)
 
 
+def _named(call):
+    """A ``pallas_call(..., name=...)`` under its own ``jit``: the jit
+    starts a fresh name stack, so the compiled custom call is named
+    ``<name>.N`` (``%lmhead_ce_bwd.2``) and not after the autodiff
+    transform it runs under (``%transpose_jvp___.2``). The device trace
+    shows ops by these instruction names."""
+    return jax.jit(call)
+
+
 # ---------------------------------------------------------------------------
 # Cache-entry storage form
 # ---------------------------------------------------------------------------
@@ -199,7 +208,7 @@ def _mix_fwd_impl(q, scale, w, a, lam, bt, bj, bk, interpret):
             f(q_ref, None, w_ref, a_ref, lam_ref, o_ref, bw_ref, acc_ref),
             f=kernel,
         )
-    out, bw = pl.pallas_call(
+    out, bw = _named(pl.pallas_call(
         kernel,
         grid=(Tp // bt, dap // bj, n_k),
         in_specs=in_specs,
@@ -213,7 +222,8 @@ def _mix_fwd_impl(q, scale, w, a, lam, bt, bj, bk, interpret):
         ),
         scratch_shapes=[pltpu.VMEM((bt, bj), jnp.float32)],
         interpret=interpret,
-    )(*args)
+        name="dq_adapter_mix_fwd",
+    ))(*args)
     return out[:T, :da], bw[:T, :da]
 
 
@@ -280,7 +290,7 @@ def _mix_dw_impl(q, scale, g, lam, d_out, out_dtype, bi, bj, bkt, interpret):
             f(q_ref, None, g_ref, lam_ref, dw_ref, acc_ref),
             f=kernel,
         )
-    dw = pl.pallas_call(
+    dw = _named(pl.pallas_call(
         kernel,
         grid=(Dp // bi, dap // bj, n_k),
         in_specs=in_specs,
@@ -288,7 +298,8 @@ def _mix_dw_impl(q, scale, g, lam, d_out, out_dtype, bi, bj, bkt, interpret):
         out_shape=jax.ShapeDtypeStruct((Dp, dap), out_dtype),
         scratch_shapes=[pltpu.VMEM((bi, bj), jnp.float32)],
         interpret=interpret,
-    )(*args)
+        name="dq_adapter_mix_dw",
+    ))(*args)
     return dw[:d_out, :da]
 
 
@@ -468,7 +479,7 @@ def _ce_fwd_impl(h, w, labels, softcap, bt, bv, interpret):
     Vp = -(-V // bv) * bv
     wp = _pad_to(w, 1, Vp)
     n_v = Vp // bv
-    nll, lse = pl.pallas_call(
+    nll, lse = _named(pl.pallas_call(
         functools.partial(
             _ce_fwd_kernel, n_v=n_v, bv=bv, V=V, softcap=softcap
         ),
@@ -492,7 +503,8 @@ def _ce_fwd_impl(h, w, labels, softcap, bt, bv, interpret):
             pltpu.VMEM((bt, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(hp, wp, lab)
+        name="lmhead_ce_fwd",
+    ))(hp, wp, lab)
     return nll[:T, 0], lse[:T, 0]
 
 
@@ -506,7 +518,7 @@ def _ce_bwd_impl(h, w, labels, lse, g, softcap, bt, bv, interpret):
     Vp = -(-V // bv) * bv
     wp = _pad_to(w, 1, Vp)
     n_v = Vp // bv
-    dh = pl.pallas_call(
+    dh = _named(pl.pallas_call(
         functools.partial(
             _ce_bwd_kernel, n_v=n_v, bv=bv, V=V, softcap=softcap
         ),
@@ -522,7 +534,8 @@ def _ce_bwd_impl(h, w, labels, lse, g, softcap, bt, bv, interpret):
         out_shape=jax.ShapeDtypeStruct((Tp, d), h.dtype),
         scratch_shapes=[pltpu.VMEM((bt, d), jnp.float32)],
         interpret=interpret,
-    )(hp, wp, lab, lsep, gp)
+        name="lmhead_ce_bwd",
+    ))(hp, wp, lab, lsep, gp)
     return dh[:T]
 
 

@@ -439,30 +439,43 @@ class EdgeSession:
         the session's adapter/opt state and returns a :class:`StepEvent`.
 
         ``batch`` is one :meth:`DataPipeline.epoch` item (``seq_ids``
-        is consumed here)."""
+        is consumed here).
+
+        Host spans (``jax.profiler.TraceAnnotation``, seen only while a
+        profiler runs): ``pac.step`` around the whole step, holding
+        ``pac.step.lookup``, ``pac.step.dispatch`` (the jitted step's
+        call), the cache's ``pac.cache.put_batch`` on a miss, and
+        ``pac.step.sync`` (``float(loss)``)."""
         import time
+
+        from jax.profiler import TraceAnnotation
 
         if not self._opened:
             raise RuntimeError("EdgeSession.step() before open() — use "
                                "`with EdgeSession(spec) as s:` or s.open()")
         t0 = time.perf_counter()
-        ids = batch.pop("seq_ids")
-        hit = self._next_hit(ids)
-        if hit is None:
-            loss, self.adapter, self.opt, (b0, taps, bf) = self._step1(
-                self.backbone, self.adapter, self.opt, batch)
-            if self.spec.use_cache:
-                # orig_last: storage-form (pallas) taps are padded to the
-                # quant block on the last axis; d_model is the true width
-                self.cache.put_batch(ids, b0, taps, bf,
-                                     orig_last=self.cfg.d_model)
-            cache_hit = False
-        else:
-            cached = self._cached_inputs(hit, batch)
-            loss, self.adapter, self.opt = self._cached_step(cached)(
-                self.backbone, self.adapter, self.opt, cached)
-            cache_hit = True
-        loss = float(loss)
+        with TraceAnnotation("pac.step"):
+            ids = batch.pop("seq_ids")
+            with TraceAnnotation("pac.step.lookup"):
+                hit = self._next_hit(ids)
+            if hit is None:
+                with TraceAnnotation("pac.step.dispatch"):
+                    loss, self.adapter, self.opt, (b0, taps, bf) = self._step1(
+                        self.backbone, self.adapter, self.opt, batch)
+                if self.spec.use_cache:
+                    # orig_last: storage-form (pallas) taps are padded to the
+                    # quant block on the last axis; d_model is the true width
+                    self.cache.put_batch(ids, b0, taps, bf,
+                                         orig_last=self.cfg.d_model)
+                cache_hit = False
+            else:
+                with TraceAnnotation("pac.step.dispatch"):
+                    cached = self._cached_inputs(hit, batch)
+                    loss, self.adapter, self.opt = self._cached_step(cached)(
+                        self.backbone, self.adapter, self.opt, cached)
+                cache_hit = True
+            with TraceAnnotation("pac.step.sync"):
+                loss = float(loss)
         return StepEvent(
             epoch=epoch, index=index, loss=loss, cache_hit=cache_hit,
             mode=self.mode(cache_hit), wall_s=time.perf_counter() - t0)

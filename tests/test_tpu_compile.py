@@ -14,6 +14,7 @@ imports this file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -120,9 +121,25 @@ CASES = {
 }
 
 
+# the instruction names the device trace shows for the cached step's
+# kernels, whatever autodiff transform they run under
+KERNEL_NAMES = {
+    "dq_adapter_mix_int8": ["dq_adapter_mix_fwd"],
+    "dq_adapter_mix_int8_grad": ["dq_adapter_mix_dw"],
+    "dq_adapter_mix_bf16": ["dq_adapter_mix_fwd"],
+    "dq_adapter_mix_bf16_grad": ["dq_adapter_mix_dw"],
+    "lmhead_ce": ["lmhead_ce_fwd"],
+    "lmhead_ce_grad": ["lmhead_ce_fwd", "lmhead_ce_bwd"],
+}
+
+
 @pytest.mark.parametrize("name", sorted(CASES))
 def test_kernel_compiles_for_v5e(one_chip, name):
     fn, shapes = CASES[name]
     args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in shapes]
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text(), name
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, name
+    customs = [ln.split(" = ", 1)[0].split()[-1] for ln in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    for kernel in KERNEL_NAMES.get(name, []):
+        assert any(re.fullmatch(rf"%{kernel}\.\d+", c) for c in customs), (kernel, customs)
