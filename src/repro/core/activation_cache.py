@@ -199,6 +199,19 @@ def _stack_parts(parts, axis: int):
     return np.stack(parts, axis=axis)
 
 
+@jax.jit
+def _join_on_device(seqs):
+    """One batch from its sequences' device-resident parts: the device
+    twin of :meth:`ActivationCache.get_batch`'s host stack — b0 and
+    b_final on axis 0, taps (n_p, S, d) on axis 1, q/scale dicts leaf by
+    leaf. A stack only copies, so the batch equals get_batch's bit for
+    bit."""
+    def stack(parts, axis):
+        return jax.tree.map(lambda *xs: jnp.stack(xs, axis=axis), *parts)
+
+    return tuple(stack(col, 1 if i == 1 else 0) for i, col in enumerate(zip(*seqs)))
+
+
 @dataclass
 class CacheEntry:
     """One sequence's cached activations: (b0, taps[, b_final])."""
@@ -631,11 +644,20 @@ class CachePrefetcher:
 
     Iterates the epoch's known batch order (``DataPipeline.epoch_order``)
     on a daemon thread, so npz reads and dequantisation of batch *k+1*
-    overlap train step *k*. With ``to_device=True`` the worker also calls
-    ``jax.device_put``, starting the host→device copy early; the bounded
-    queue (``depth``, default 2) double-buffers: one batch in flight
-    while one is being consumed, and the thread blocks rather than
-    loading the whole epoch ahead.
+    overlap train step *k*. The bounded queue (``depth``, default 2)
+    double-buffers: one batch in flight while one is being consumed, and
+    the thread blocks rather than loading the whole epoch ahead.
+
+    Where the batch is joined depends on ``to_device``. With
+    ``to_device=True`` it is joined on the device: the worker reads each
+    sequence's parts as :meth:`ActivationCache.get` returns them (in
+    storage form, the entry's own arrays: no host copy) and enqueues
+    their host→device copy with one ``jax.device_put``; the consumer
+    stacks the pieces with one jitted join right after the queue get, on
+    its own thread, so the join queues behind the step before it. With
+    ``to_device=False`` it is joined on the host by
+    :meth:`ActivationCache.get_batch`, and the batch stays in host
+    memory.
 
     Yields one ``(b0, taps[, b_final])`` tuple per key-batch, in order —
     or ``None`` for a batch with a missing key (the consumer falls back
@@ -654,9 +676,12 @@ class CachePrefetcher:
     queued ``device_put`` results until process exit.
 
     Host spans: ``pac.prefetch.wait`` on the consumer's thread around the
-    blocking queue get (``n``: the batch's ordinal, 0 for the first);
-    ``pac.prefetch.load`` and ``pac.prefetch.device_put`` on the worker
-    (the latter with ``nbytes``, the batch's storage-form bytes).
+    blocking queue get (``n``: the batch's ordinal, 0 for the first); the
+    device join's dispatch follows it, under the caller's span
+    (``pac.step.lookup`` in ``EdgeSession.step``). ``pac.prefetch.load``
+    (the cache reads, and the host join for ``to_device=False``) and
+    ``pac.prefetch.device_put`` on the worker (the latter with
+    ``nbytes``, the batch's storage-form bytes).
     """
 
     _DONE = object()
@@ -695,22 +720,30 @@ class CachePrefetcher:
                 if self._stop.is_set():
                     break
                 with TraceAnnotation("pac.prefetch.load"):
-                    got = self._cache.get_batch(
-                        keys, with_final=self._with_final, dtype=self._dtype,
-                        compressed=self._compressed,
-                    )
+                    got = self._load(keys)
                 if got is not None and self._to_device:
                     # device_put handles the storage-form pytrees too
                     # ({"q","scale"} dicts ship at integer width); the
                     # span covers the enqueue, not the copy's end
-                    nbytes = sum(_part_nbytes(g) for g in got)
+                    nbytes = sum(_part_nbytes(p) for seq in got for p in seq)
                     with TraceAnnotation("pac.prefetch.device_put", nbytes=nbytes):
-                        got = tuple(jax.device_put(g) for g in got)
+                        got = jax.device_put(got)
                 self._q.put(got)
         except BaseException as e:  # surfaced on the consumer side
             self._err = e
         finally:
             self._q.put(self._DONE)
+
+    def _load(self, keys):
+        """The batch for ``keys``, or None if a key misses. For the host,
+        the batch ``get_batch`` joins; for the device, each sequence's
+        parts as ``get`` returns them, which ``__next__`` joins there."""
+        kw = dict(with_final=self._with_final, dtype=self._dtype,
+                  compressed=self._compressed)
+        if not self._to_device:
+            return self._cache.get_batch(keys, **kw)
+        seqs = [self._cache.get(int(k), **kw) for k in keys]
+        return None if any(seq is None for seq in seqs) else seqs
 
     def __iter__(self):
         return self
@@ -733,6 +766,8 @@ class CachePrefetcher:
             if self._err is not None:
                 raise self._err
             raise StopIteration
+        if item is not None and self._to_device:
+            item = _join_on_device(item)
         return item
 
     def __enter__(self) -> "CachePrefetcher":

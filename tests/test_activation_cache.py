@@ -5,6 +5,7 @@ and cross-run persistence."""
 import os
 import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -353,25 +354,62 @@ def test_get_batch_with_final_and_raw_dtype():
 # ---------------------------------------------------------------------------
 
 
-def _filled_cache(n=8, spill_dir=None, budget=1 << 24):
-    cache = ActivationCache(budget_bytes=budget, spill_dir=spill_dir)
+def _filled_cache(n=8, spill_dir=None, budget=1 << 24, compress="f32"):
+    cache = ActivationCache(budget_bytes=budget, spill_dir=spill_dir, compress=compress)
     for k in range(n):
         cache.put(k, *_entry_f(k, d=32))
     return cache
 
 
-def test_prefetcher_matches_sync_reads(tmp_path):
+@pytest.mark.parametrize("to_device", [False, True], ids=["host", "device"])
+@pytest.mark.parametrize("policy,dtype,compressed", [
+    ("f32", np.float32, False),
+    ("bf16", None, False),
+    ("int8", np.float32, False),
+    ("int8", None, True),
+], ids=["f32", "bf16-raw", "int8", "int8-storage"])
+def test_prefetcher_matches_sync_reads(tmp_path, to_device, policy, dtype, compressed):
     """The prefetcher yields exactly what synchronous get_batch returns,
-    in batch order — including entries that must come off disk."""
-    one = sum(a.nbytes for a in _entry_f(0, d=32))
-    cache = _filled_cache(8, spill_dir=str(tmp_path), budget=3 * one)
-    order = [np.array([0, 5]), np.array([2, 7]), np.array([4, 1]), np.array([6, 3])]
-    want = [cache.get_batch(keys, with_final=True) for keys in order]
-    got = list(CachePrefetcher(cache, order, to_device=False))
+    in batch order: the same tree, shapes, dtypes and bits, whether the
+    batch is joined on the host or on the device — including entries
+    that must come off disk and a short last batch."""
+    sizer = _filled_cache(1, compress=policy)
+    cache = _filled_cache(11, spill_dir=str(tmp_path), budget=3 * sizer.nbytes,
+                          compress=policy)
+    order = [np.array([0, 5, 9, 2]), np.array([7, 4, 1, 10]), np.array([6, 3, 8])]
+    kw = dict(with_final=True, dtype=dtype, compressed=compressed)
+    want = [cache.get_batch(keys, **kw) for keys in order]
+    got = list(CachePrefetcher(cache, order, to_device=to_device, **kw))
     assert len(got) == len(want)
     for w, g in zip(want, got):
-        for a, b in zip(w, g):
-            np.testing.assert_array_equal(a, b)
+        assert jax.tree.structure(g) == jax.tree.structure(w)
+        for a, b in zip(jax.tree.leaves(w), jax.tree.leaves(g)):
+            assert isinstance(b, jax.Array) == to_device
+            b = np.asarray(b)
+            assert (b.shape, b.dtype) == (a.shape, a.dtype)
+            assert b.tobytes() == a.tobytes()
+    assert [jax.tree.leaves(g[0])[0].shape[0] for g in got] == [4, 4, 3]
+
+
+class _HostStack(Exception):
+    pass
+
+
+def test_prefetcher_joins_on_the_device_without_a_host_stack(monkeypatch):
+    """The device-bound prefetcher never stacks a batch on the host; the
+    host-bound one does, through get_batch."""
+    from repro.core import activation_cache
+
+    def refuse(parts, axis):
+        raise _HostStack
+
+    monkeypatch.setattr(activation_cache, "_stack_parts", refuse)
+    cache = _filled_cache(6, compress="int8")
+    order = [np.array([0, 1, 2, 3]), np.array([4, 5])]
+    got = list(CachePrefetcher(cache, order, to_device=True, compressed=True))
+    assert [g[0]["q"].shape[0] for g in got] == [4, 2]
+    with pytest.raises(_HostStack):
+        list(CachePrefetcher(cache, order, to_device=False, compressed=True))
 
 
 def test_prefetcher_device_put_yields_jax_arrays():

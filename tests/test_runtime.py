@@ -220,6 +220,39 @@ def test_session_matches_composed_steps_pallas_interpret():
     assert reports[1].used_cache and reports[1].mode == "cached"
 
 
+def test_cached_epoch_same_from_prefetcher_as_from_get_batch():
+    """A cached epoch fed by the prefetcher (its batches joined on the
+    device) gives the losses and adapter, bit for bit, that the same
+    epoch gives fed by the cache's host-joined ``get_batch``."""
+    import contextlib
+
+    import jax
+    import numpy as np
+
+    from repro.runtime import EdgeSession
+
+    spec = RunSpec(arch="internlm2-1.8b", reduced=True, epochs=2,
+                   steps_per_epoch=2, batch=2, seq=16, r=4, lr=1e-3,
+                   cache_compress="int8", kernels="pallas")
+
+    def cached_epoch(prefetch: bool):
+        with EdgeSession(spec) as s:
+            for batch in s.pipe.epoch(0):
+                s.step(batch)
+            scope = s.epoch_scope(1) if prefetch else contextlib.nullcontext(False)
+            with scope as from_prefetcher:
+                assert from_prefetcher == prefetch
+                events = [s.step(batch, epoch=1) for batch in s.pipe.epoch(1)]
+            assert all(e.cache_hit for e in events)
+            return [e.loss for e in events], jax.device_get(s.adapter)
+
+    losses, adapter = cached_epoch(prefetch=True)
+    want_losses, want_adapter = cached_epoch(prefetch=False)
+    assert losses == want_losses
+    for a, b in zip(jax.tree.leaves(adapter), jax.tree.leaves(want_adapter)):
+        np.testing.assert_array_equal(a, b)
+
+
 _GOLDEN_DP = textwrap.dedent(
     """
     import functools
