@@ -14,10 +14,10 @@ from bench import trace
 
 
 def is_lmhead_ce(config):
-    """The kernel's two custom calls carry no name of their own in the
-    trace (``%jvp__.3``, ``%transpose_jvp___.2`` on a TPU v5e): they are
-    the Pallas calls that take the whole f32 head, (d, vocab padded up),
-    as an operand."""
+    """The kernel's two custom calls, ``%lmhead_ce_fwd.N`` and
+    ``%lmhead_ce_bwd.N`` in a TPU v5e trace: the Pallas calls that take
+    the whole f32 head, (d, vocab padded up), as an operand, and are
+    matched by that operand."""
     d, V = config["arch"]["d_model"], config["arch"]["vocab"]
     head = re.compile(rf"f32\[{d},(\d+)\]")
 

@@ -1,25 +1,25 @@
 """Model FLOP/s utilisation of the engine's steps, against bf16 peak:
 the forward FLOPs of the prompt tokens prefilled and of the tokens
 decoded in the traced window's engine steps, over the summed wall of
-those steps. Per token: the backbone's projections and causal
-attention over its context, the side network's forward, and one LM-head
-row for every token produced."""
+those steps. Per token: the frozen backbone (the architecture's
+``frozen_flops_per_token``) over its context, the side network's
+forward, and one LM-head row for every token produced."""
 
-
-def _proj(w):
-    d, hd = w["d_model"], w["head_dim"]
-    hq, hkv = w["n_heads"] * hd, w["n_kv_heads"] * hd
-    return 2 * d * (hq + 2 * hkv) + 2 * hq * d + 3 * 2 * d * w["d_ff"], hq
+from bench import manifest
+from bench import weights as W
 
 
 def token_flops(config, ctx):
     """Forward FLOPs of one token that attends over ``ctx`` positions,
-    without the head."""
+    without the head. The backbone's is the ``ctx``-th token's share of a
+    causal row: ctx F(ctx) - (ctx - 1) F(ctx - 1), F its per-token mean."""
     a, ad = config["arch"], config["adapter"]
-    L, d, da = a["n_layers"], a["d_model"], ad["d_model"]
-    p, hq = _proj(a)
-    pa, hqa = _proj(ad)
-    return L * (p + 4 * hq * ctx) + L * (pa + 4 * hqa * ctx) + 2 * (L + 1) * d * da + 2 * da * d
+    d, da = a["d_model"], ad["d_model"]
+    taps, windows = manifest.side(config)
+    frozen = manifest.arch_module(config).frozen_flops_per_token
+    backbone = ctx * frozen(a, ctx) - ((ctx - 1) * frozen(a, ctx - 1) if ctx > 1 else 0)
+    side = taps * sum(W.layer_flops(ad, ctx if w is None else min(ctx, w)) for w in windows)
+    return backbone + side + 2 * (taps + 1) * d * da + 2 * da * d
 
 
 def step_flops(config, step):

@@ -1,21 +1,15 @@
 """Share of its roofline that ``kernels/paged_attention`` reaches: one
-call per layer per decode step. For a row whose context is ``c``
-tokens: 4 x n_heads x head_dim x c operations (scores and values) and
-the int8 keys and values of its ``c`` tokens with their f32 scales per
-KV head, plus the f32 query and output rows. Memory bound at any
-context on this chip."""
+call per layer per decode step, its operations and bytes the
+architecture's (``paged_attention_work``). Memory bound at any context
+on this chip."""
 
-from bench import trace
+from bench import manifest, trace
 
 PATTERN = r"paged_attention"
 
 
 def step_work(config, ctx):
-    a = config["arch"]
-    hq, hkv, hd = a["n_heads"] * a["head_dim"], a["n_kv_heads"], a["head_dim"]
-    flops = sum(4 * hq * c for c in ctx)
-    kv = sum(c * hkv * (2 * hd + 2 * 4) for c in ctx)
-    return a["n_layers"] * flops, a["n_layers"] * (kv + len(ctx) * 2 * 4 * hq)
+    return manifest.arch_module(config).paged_attention_work(config["arch"], ctx)
 
 
 def read(record):

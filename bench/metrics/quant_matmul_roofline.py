@@ -3,13 +3,13 @@ capture step: the least time the chip could take for every call of the
 traced window — the larger of its operations over the bf16 peak and its
 bytes over HBM bandwidth — over the summed device time of its events.
 
-Calls per step, from shapes: the seven projections of each layer
-(q, k, v, o; gate, up, down) at M = batch x seq rows. Bytes: the f32
-activations in and out, the int8 weight and its f32 scales (one per
-128 columns).
+Calls per step, from shapes: the architecture's
+(``quant_matmul_calls``; the dense decoder's seven projections of each
+layer) at M = batch x seq rows. Bytes: the f32 activations in and out,
+the int8 weight and its f32 scales (one per 128 columns).
 """
 
-from bench import trace
+from bench import manifest, trace
 
 # the kernel's custom calls are named after the jitted function:
 # %quant_matmul.49 ... %quant_matmul.55 in a TPU v5e trace of the capture step
@@ -19,13 +19,8 @@ QBLOCK = 128
 
 def calls(config, traffic):
     """(M, K, N) of every quant_matmul call of one capture step."""
-    a = config["arch"]
-    d, ff, hd = a["d_model"], a["d_ff"], a["head_dim"]
-    hq, hkv = a["n_heads"] * hd, a["n_kv_heads"] * hd
     M = traffic["batch"] * traffic["seq"]
-    layer = [(M, d, hq), (M, d, hkv), (M, d, hkv), (M, hq, d),
-             (M, d, ff), (M, d, ff), (M, ff, d)]
-    return layer * a["n_layers"]
+    return manifest.arch_module(config).quant_matmul_calls(config["arch"], M)
 
 
 def flops(M, K, N):

@@ -13,12 +13,12 @@ import gc
 import jax
 import jax.numpy as jnp
 
+from bench import manifest
 from bench import weights as W
 
-# arch keys of a configuration file and the ArchConfig attribute each is
-_ARCH_ATTRS = {"n_layers": "n_layers", "d_model": "d_model", "n_heads": "n_heads",
-               "n_kv_heads": "n_kv_heads", "head_dim": "hd", "d_ff": "d_ff",
-               "vocab": "vocab", "rope_theta": "rope_theta", "norm_eps": "norm_eps"}
+# adapter keys of a configuration file and the ArchConfig attribute each is
+_ADAPTER_ATTRS = {"d_model": "d_model", "n_heads": "n_heads", "n_kv_heads": "n_kv_heads",
+                  "head_dim": "hd", "d_ff": "d_ff"}
 
 
 def program_arch(config: dict) -> str:
@@ -27,25 +27,11 @@ def program_arch(config: dict) -> str:
     return "bench." + config["name"]
 
 
-def arch_config(config: dict):
-    """The program's ArchConfig for ``config``, registered from the file
-    alone: a dense decoder of attention layers at the file's widths."""
-    from repro.configs import ArchConfig, LayerSpec, register
-
-    arch = config["arch"]
-    return register(ArchConfig(
-        name=program_arch(config), family="dense", n_layers=arch["n_layers"],
-        d_model=arch["d_model"], n_heads=arch["n_heads"], n_kv_heads=arch["n_kv_heads"],
-        head_dim=arch["head_dim"], d_ff=arch["d_ff"], vocab=arch["vocab"],
-        pattern=(LayerSpec(kind="attn"),), rope_theta=arch["rope_theta"],
-        norm_eps=arch["norm_eps"], source=config["source"]))
-
-
 def check_adapter(config: dict, cfg) -> None:
     from repro.core.parallel_adapters import adapter_config
 
     acfg = adapter_config(cfg, config["run"]["r"])
-    got = {k: getattr(acfg, _ARCH_ATTRS[k]) for k in config["adapter"]}
+    got = {k: getattr(acfg, _ADAPTER_ATTRS[k]) for k in config["adapter"]}
     if got != config["adapter"]:
         raise ValueError(f"side network widths {got} differ from the file's "
                          f"{config['adapter']}")
@@ -73,28 +59,31 @@ def _same_tree(a, b, what: str) -> None:
 
 def make_backbone(config: dict, seed: int, bits: int):
     """The benchmark's backbone from the seed, quantised by the program's
-    ``quantize_tree`` one layer at a time, then stacked: at most one
-    layer's float32 weights are live, so the build's peak stays well
-    under the program's own."""
+    ``quantize_tree`` one layer at a time, then stacked, stack by stack
+    of the architecture's ``stacks``: at most one layer's float32 weights
+    are live, so the build's peak stays well under the program's own."""
     from repro.core.quantization import quantize_tree
 
+    mod = manifest.arch_module(config)
     arch, key = config["arch"], W.seed_key(seed, W.STREAM_BACKBONE)
-    n = arch["n_layers"]
-    # a (1, ...) slice of a stacked leaf is quantised where the stacked
-    # leaf of n layers would be: n * size >= quant_min_size
-    min_size = -(-config["quant_min_size"] // n)
+    concat = jax.jit(lambda ls: jax.tree.map(lambda *xs: jnp.concatenate(xs), *ls))
+    stacked = []
+    for stack in mod.stacks(arch):
+        # a (1, ...) slice of a stacked leaf is quantised where the stacked
+        # leaf of n layers would be: n * size >= quant_min_size
+        min_size = -(-config["quant_min_size"] // stack.count)
 
-    @jax.jit
-    def layer(k, i):
-        one = jax.tree.map(lambda x: x[None], W.make_layer(k, arch, i))
-        return quantize_tree(W.nest(one), bits=bits, min_size=min_size)
+        @jax.jit
+        def layer(k, i, make=stack.make, min_size=min_size):
+            one = jax.tree.map(lambda x: x[None], make(k, i))
+            return quantize_tree(one, bits=bits, min_size=min_size, skip_names=W.F32_NAMES)
 
-    layers = [layer(key, jnp.int32(i)) for i in range(n)]
-    blocks = jax.jit(lambda ls: jax.tree.map(lambda *xs: jnp.concatenate(xs), *ls))(layers)
-    del layers
+        layers = [layer(key, jnp.int32(i)) for i in range(stack.count)]
+        stacked.append(concat(layers))
+        del layers
     rest = jax.jit(lambda k: quantize_tree(W.make_rest(k, arch), bits=bits,
                                            min_size=config["quant_min_size"]))(key)
-    return dict(rest, blocks=[blocks])
+    return mod.backbone_tree(rest, stacked)
 
 
 def open_session(config: dict, traffic: dict, seed: int, marks=None, **override):
@@ -105,7 +94,7 @@ def open_session(config: dict, traffic: dict, seed: int, marks=None, **override)
     from bench.harness import now
     from repro.runtime import EdgeSession
 
-    cfg = arch_config(config)
+    cfg = manifest.arch_module(config).program_config(config)
     check_adapter(config, cfg)
     spec = run_spec(config, traffic, seed, **override)
     session = EdgeSession(spec).open()
@@ -118,7 +107,8 @@ def open_session(config: dict, traffic: dict, seed: int, marks=None, **override)
     arch = config["arch"]
     session.backbone = make_backbone(config, seed, spec.quant)
     _same_tree(session.backbone, layout, "backbone")
-    adapter = jax.jit(lambda k: W.make_adapter(k, arch, config["adapter"]))(
+    side = manifest.side(config)
+    adapter = jax.jit(lambda k: W.make_adapter(k, arch, config["adapter"], side))(
         W.seed_key(seed, W.STREAM_ADAPTER))
     _same_tree(adapter, own_adapter, "adapter")
     del own_adapter

@@ -1,4 +1,4 @@
-"""Plain float32 reference of InternLM2 with a Parallel Adapter.
+"""Plain float32 reference of a frozen backbone with a Parallel Adapter.
 
 Straightforward ``jax.numpy`` at ``Precision.HIGHEST``: no kernels, no
 cache, no batching tricks, and nothing imported from the program. It
@@ -7,16 +7,22 @@ follows the configuration file: weights made again from the seed by
 configuration states (block-absmax INT8, ``weight_block`` values per
 scale along the last axis, on every stacked leaf of at least
 ``quant_min_size`` values: the per-layer norm gains too), taps at the stated tap precision
-(``tap_block``), attention, RMSNorm with a ``1 + w`` gain, rotary
-embedding over the two halves of each head, GQA (query head ``h`` reads
-KV head ``h // (n_heads / n_kv_heads)``), a SiLU-gated MLP, and the side
-network of the PAC paper (Parallel Adapters, arXiv:2408.10746 §IV-A):
+(``tap_block``). The backbone's layers are its architecture's
+(``bench/archs/<architecture>.py``: ``stacks``, ``depth_order``,
+``reference_layer``); a tap follows each group of ``depth_order``.
 
-    a_0 = b_0 W_down[0];  a_i = block_i(λ_i b_i W_down[i] + (1 - λ_i) a_{i-1})
+The side network is the PAC paper's (Parallel Adapters,
+arXiv:2408.10746 §IV-A), made of dense decoder layers (``block``):
+attention, RMSNorm with a ``1 + w`` gain, rotary embedding over the two
+halves of each head, GQA (query head ``h`` reads KV head
+``h // (n_heads / n_kv_heads)``), a SiLU-gated MLP:
+
+    a_0 = b_0 W_down[0];  a_i = blocks_i(λ_i b_i W_down[i] + (1 - λ_i) a_{i-1})
     logits = RMSNorm(b_final + RMSNorm(a_n) W_up) W_head
 
-with λ clipped to [0, 1]. The step clips the gradient to a global norm
-and applies AdamW, with the settings of the configuration's
+with λ clipped to [0, 1] and ``blocks_i`` one dense block per entry of
+the architecture's ``side_windows``. The step clips the gradient to a
+global norm and applies AdamW, with the settings of the configuration's
 ``optimizer`` section.
 
 The backbone runs one layer per call, so that the reference fits on the
@@ -30,6 +36,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from bench import manifest
 from bench import weights as W
 
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -66,7 +73,14 @@ def rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
 
 
-def attention(x, p, w: dict, theta):
+def visible(S: int, window=None):
+    """(S, S) mask: query row q sees key k when k <= q, and with a
+    ``window``, when q - k < window."""
+    lag = jnp.arange(S)[:, None] - jnp.arange(S)[None, :]
+    return (lag >= 0) if window is None else (lag >= 0) & (lag < window)
+
+
+def attention(x, p, w: dict, theta, window=None):
     B, S, _ = x.shape
     H, Hkv, hd = w["n_heads"], w["n_kv_heads"], w["head_dim"]
     q = rope(mm(x, p["wq"]).reshape(B, S, H, hd), theta)
@@ -75,8 +89,7 @@ def attention(x, p, w: dict, theta):
     kv_of = jnp.arange(H) // (H // Hkv)
     k, v = k[:, :, kv_of], v[:, :, kv_of]
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision=HIGHEST) * hd ** -0.5
-    causal = jnp.arange(S)[:, None] >= jnp.arange(S)[None, :]
-    s = jnp.where(causal, s, -jnp.inf)
+    s = jnp.where(visible(S, window), s, -jnp.inf)
     o = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v, precision=HIGHEST)
     return mm(o.reshape(B, S, H * hd), p["wo"])
 
@@ -91,12 +104,11 @@ def served_attention(x, p, w: dict, theta, prompt_len):
     k = rope(mm(x, p["wk"]).reshape(B, S, Hkv, hd), theta)
     v = mm(x, p["wv"]).reshape(B, S, Hkv, hd)
     kv_of = jnp.arange(H) // (H // Hkv)
-    causal = jnp.arange(S)[:, None] >= jnp.arange(S)[None, :]
 
     def attend(k, v):
         k, v = k[:, :, kv_of], v[:, :, kv_of]
         s = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision=HIGHEST) * hd ** -0.5
-        s = jnp.where(causal, s, -jnp.inf)
+        s = jnp.where(visible(S), s, -jnp.inf)
         return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v, precision=HIGHEST)
 
     decode_row = (jnp.arange(S) >= prompt_len)[None, :, None, None]
@@ -104,11 +116,13 @@ def served_attention(x, p, w: dict, theta, prompt_len):
     return mm(o.reshape(B, S, H * hd), p["wo"])
 
 
-def block(p, x, w: dict, eps, theta, prompt_len=None):
-    """One decoder layer; ``p`` is flat by leaf name (weights.layer_shapes).
-    With ``prompt_len``, attention is the served path's."""
+def block(p, x, w: dict, eps, theta, prompt_len=None, window=None):
+    """One dense decoder layer; ``p`` is flat by leaf name
+    (weights.layer_shapes). With ``prompt_len``, attention is the served
+    path's (full causal); with ``window``, each row sees its last
+    ``window`` positions."""
     if prompt_len is None:
-        mix = attention(rms_norm(x, p["ln1"], eps), p, w, theta)
+        mix = attention(rms_norm(x, p["ln1"], eps), p, w, theta, window)
     else:
         mix = served_attention(rms_norm(x, p["ln1"], eps), p, w, theta, prompt_len)
     x = x + mix
@@ -127,6 +141,9 @@ class Reference:
 
     def __init__(self, config: dict, seed: int):
         self.arch, self.ad = config["arch"], config["adapter"]
+        self.mod = manifest.arch_module(config)
+        self.stacks = self.mod.stacks(self.arch)
+        self.side_shape = manifest.side(config)
         self.bits = config["run"]["quant"]
         self.wblock, self.tblock = config["weight_block"], config["tap_block"]
         self.qmin = config["quant_min_size"]
@@ -146,12 +163,15 @@ class Reference:
 
     @functools.cached_property
     def _layer(self):
-        def run(key, layer, x, prompt_len=None):
-            n_layers = self.arch["n_layers"]
-            p = {n: self.wq(v) if n_layers * v.size >= self.qmin else v
-                 for n, v in W.make_layer(key, self.arch, layer).items()}
-            return block(p, x, self.arch, self.eps, self.theta, prompt_len)
-        return jax.jit(run)
+        def run(key, stack, layer, x, prompt_len=None):
+            n_layers = self.stacks[stack].count
+            p = {n: self.wq(v) if n_layers * v.size >= self.qmin
+                 and not any(f in n for f in W.F32_NAMES) else v
+                 for n, v in self.stacks[stack].make(key, layer).items()}
+            if prompt_len is None:
+                return self.mod.reference_layer(p, x, self.arch, stack)
+            return self.mod.reference_layer(p, x, self.arch, stack, prompt_len)
+        return jax.jit(run, static_argnums=1)
 
     @functools.cached_property
     def _embed(self):
@@ -163,7 +183,8 @@ class Reference:
         return fn, jax.jit(self.wq)(head)
 
     def initial_adapter(self):
-        return jax.jit(lambda k: W.make_adapter(k, self.arch, self.ad))(self.adapter_key)
+        return jax.jit(lambda k: W.make_adapter(k, self.arch, self.ad, self.side_shape))(
+            self.adapter_key)
 
     # -- frozen backbone ----------------------------------------------------
 
@@ -172,8 +193,9 @@ class Reference:
         one layer per call."""
         x = self._embed(self.key, jnp.asarray(tokens))
         b0, taps = self.tap(x), []
-        for layer in range(self.arch["n_layers"]):
-            x = self._layer(self.key, layer, x)
+        for group in self.mod.depth_order(self.arch):
+            for stack, layer in group:
+                x = self._layer(self.key, stack, layer, x)
             taps.append(self.tap(x))
         return b0, jnp.stack(taps), self.tap(x)
 
@@ -184,8 +206,9 @@ class Reference:
         at the end (causal rows before the padding are unaffected)."""
         x = self._embed(self.key, jnp.asarray(tokens)[None])
         b0, taps = x, []
-        for layer in range(self.arch["n_layers"]):
-            x = self._layer(self.key, layer, x, jnp.int32(prompt_len))
+        for group in self.mod.depth_order(self.arch):
+            for stack, layer in group:
+                x = self._layer(self.key, stack, layer, x, jnp.int32(prompt_len))
             taps.append(x)
         return self._served_head(ap, b0, jnp.stack(taps), x, *head)[0]
 
@@ -198,14 +221,17 @@ class Reference:
     def side(self, ap, b0, taps):
         eps, theta = self.eps, self.theta
         lam = jnp.clip(ap["lambda"], 0.0, 1.0)
+        _, windows = self.side_shape
 
         def period(a, xs):
-            blk, down, lam_i, b_i = xs
-            mixed = lam_i * mm(b_i, down) + (1.0 - lam_i) * a
-            return block(_flat(blk), mixed, self.ad, eps, theta), None
+            blks, down, lam_i, b_i = xs
+            h = lam_i * mm(b_i, down) + (1.0 - lam_i) * a
+            for blk, window in zip(blks, windows):
+                h = block(_flat(blk), h, self.ad, eps, theta, window=window)
+            return h, None
 
         a, _ = jax.lax.scan(period, mm(b0, ap["downs"][0]),
-                            (ap["blocks"][0], ap["downs"][1:], lam, taps))
+                            (tuple(ap["blocks"]), ap["downs"][1:], lam, taps))
         return mm(rms_norm(a, ap["out_norm"], eps), ap["up"])
 
     def logits(self, ap, b0, taps, bf, final_norm, head):

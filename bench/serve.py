@@ -19,12 +19,13 @@ from __future__ import annotations
 
 import contextlib
 import gc
+import inspect
 import time
 
 import jax
 import numpy as np
 
-from bench import model, traffic
+from bench import manifest, model, traffic
 from bench import weights as W
 from bench.harness import TRACE_MAX_S, CompileCounter, now, peak_bytes, profiled, span
 from bench.reference import Reference
@@ -43,9 +44,23 @@ def _pow2_up_to(lo: int, hi: int) -> list:
     return out + [hi]
 
 
+def adapter_maker(config: dict):
+    """Jitted: the bank's adapter from its key (``STREAM_BANK + i``)."""
+    side = manifest.side(config)
+    return jax.jit(lambda k: W.make_adapter(k, config["arch"], config["adapter"], side))
+
+
 def make_bank(config: dict, seed: int, n: int) -> dict:
-    make = jax.jit(lambda k: W.make_adapter(k, config["arch"], config["adapter"]))
+    make = adapter_maker(config)
     return {f"a{i}": make(W.seed_key(seed, W.STREAM_BANK + i)) for i in range(n)}
+
+
+def check_served_path(config: dict) -> None:
+    """Refuse an architecture whose reference has no served path."""
+    mod = manifest.arch_module(config)
+    if "prompt_len" not in inspect.signature(mod.reference_layer).parameters:
+        raise ValueError(f"architecture {config['architecture']!r} gives no served path "
+                         f"(reference_layer takes no prompt_len): it cannot be served")
 
 
 def warm_up(engine, eng: dict, tr: dict) -> None:
@@ -87,6 +102,7 @@ class _Client:
 def run(cell, seed: int, seconds: float, *, t_start: float, trace_dir=None,
         override=None, fault=None) -> dict:
     config, tr = cell.config, cell.traffic
+    check_served_path(config)
     eng, vocab = tr["engine"], config["arch"]["vocab"]
     limit = min(seconds, TRACE_MAX_S) if trace_dir else seconds
     reqs = traffic.requests(seed, tr, vocab, int(tr["rate_per_s"] * max(limit, 1) * 2) + 64)
@@ -183,11 +199,11 @@ def served_numbers(config: dict, seed: int, tr: dict, served: list) -> dict:
     ref = Reference(config, seed)
     head = ref.head()
     adapters, worst, n_tok = {}, 0.0, 0
+    make = adapter_maker(config)
     for req, toks in sample(seed, served, tr["sample_requests"]):
         a = req["adapter"]
         if a not in adapters:
-            adapters[a] = jax.jit(lambda k: W.make_adapter(k, config["arch"], config["adapter"]))(
-                W.seed_key(seed, W.STREAM_BANK + a))
+            adapters[a] = make(W.seed_key(seed, W.STREAM_BANK + a))
         P, seq = len(req["prompt"]), np.concatenate([req["prompt"], toks[:-1]]).astype(np.int32)
         padded = np.zeros(-(-len(seq) // REF_BUCKET) * REF_BUCKET, np.int32)
         padded[: len(seq)] = seq

@@ -99,7 +99,7 @@ def run(cell, seed: int, seconds: float, *, t_start: float, trace_dir=None,
         tokens_per_step = tr["batch"] * tr["seq"]
         if window:
             limit = min(seconds, TRACE_MAX_S) if trace_dir else seconds
-            walls, window_losses = [], []
+            window_losses = []
             out["setup_s"] = now() - t_start
             compiles.active = True
             with profiled(trace_dir), span("bench.window"):
@@ -109,16 +109,15 @@ def run(cell, seed: int, seconds: float, *, t_start: float, trace_dir=None,
                         batch = next(stream)
                     with span("bench.step"):
                         event = session.step(batch)
-                    walls.append(event.wall_s)
                     window_losses.append(event.loss)
                     if now() - t0 >= limit:
                         break
                 out["window_s"] = now() - t0
             compiles.active = False
-            out.update(steps=len(walls), tokens=len(walls) * tokens_per_step,
-                       step_walls=walls, attempted=len(walls),
+            steps = len(window_losses)
+            out.update(steps=steps, tokens=steps * tokens_per_step, attempted=steps,
                        failed=int(sum(not np.isfinite(x) for x in window_losses)),
-                       e2e={"train_tokens_per_s": len(walls) * tokens_per_step / out["window_s"]})
+                       e2e={"train_tokens_per_s": steps * tokens_per_step / out["window_s"]})
         out["compiles_in_window"] = compiles.n
         out["info"] = {"setup_marks_s": {k: v - t_start for k, v in marks.items()}, "compile": compiles.summary()}
         out["peak_bytes"] = peak_bytes()

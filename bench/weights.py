@@ -9,10 +9,15 @@ stacked tree (one jitted call) and a single layer agree bit for bit.
 
 Shapes come from a configuration file's ``arch`` and ``adapter``
 sections (widths as published; ``adapter`` is the side network at
-``d/r``). Nothing here imports the program.
+``d/r``). The backbone's layers belong to its architecture's module
+(``bench/archs``), which owns their leaf ids; the ids here are the
+shared pieces: embedding, final norm, head and side network. Nothing
+here imports the program.
 """
 
 from __future__ import annotations
+
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -23,7 +28,8 @@ STREAM_BACKBONE = 1
 STREAM_ADAPTER = 2
 STREAM_BANK = 1000  # + i: the i-th adapter of a serving bank
 
-# one id per leaf name, so adding a leaf never shifts another's values
+# one id per leaf name, so adding a leaf never shifts another's values;
+# 10-18 are the side network's blocks (its own key, STREAM_ADAPTER)
 _LEAF_IDS = {
     "embed": 1, "final_norm": 2, "lm_head": 3,
     "ln1": 10, "wq": 11, "wk": 12, "wv": 13, "wo": 14,
@@ -31,6 +37,9 @@ _LEAF_IDS = {
     "downs": 30, "lambda": 31, "up": 32, "out_norm": 33,
 }
 NORM_SCALE = 0.1  # norm gains are (1 + w): small random w exercises them
+# a backbone leaf whose name holds one of these stays float32 whatever its
+# size, as a deployment keeps a router's weights
+F32_NAMES = ("router",)
 
 
 def seed_key(seed: int, stream: int) -> jax.Array:
@@ -40,14 +49,27 @@ def seed_key(seed: int, stream: int) -> jax.Array:
     return jnp.asarray(words, jnp.uint32)
 
 
-def _leaf(key, name: str, layer, shape, scale) -> jax.Array:
-    k = jax.random.fold_in(jax.random.fold_in(key, _LEAF_IDS[name]), layer)
+class Stack(NamedTuple):
+    """``count`` backbone layers alike: ``make(key, i)`` gives layer
+    ``i``'s f32 leaves, flat by leaf name (``i`` may be traced)."""
+
+    count: int
+    make: Callable
+
+
+def leaf(key, leaf_id: int, layer, shape, scale) -> jax.Array:
+    """``normal(fold_in(fold_in(key, leaf_id), layer)) * scale`` in f32."""
+    k = jax.random.fold_in(jax.random.fold_in(key, leaf_id), layer)
     return jax.random.normal(k, shape, jnp.float32) * scale
 
 
+def _leaf(key, name: str, layer, shape, scale) -> jax.Array:
+    return leaf(key, _LEAF_IDS[name], layer, shape, scale)
+
+
 def layer_shapes(w: dict) -> dict:
-    """name -> (shape, init scale) of one decoder layer of widths ``w``
-    (an ``arch`` or ``adapter`` section)."""
+    """name -> (shape, init scale) of one dense GQA decoder layer of
+    widths ``w`` (an ``arch`` or ``adapter`` section)."""
     d, ff = w["d_model"], w["d_ff"]
     hq, hkv = w["n_heads"] * w["head_dim"], w["n_kv_heads"] * w["head_dim"]
     return {
@@ -59,9 +81,26 @@ def layer_shapes(w: dict) -> dict:
     }
 
 
-def make_layer(key, w: dict, layer) -> dict:
-    """One layer's f32 weights, flat by leaf name (``layer`` may be traced)."""
-    return {n: _leaf(key, n, layer, s, c) for n, (s, c) in layer_shapes(w).items()}
+def causal_context(seq: int, window=None) -> float:
+    """Mean number of positions a token of a causal row of ``seq`` tokens
+    attends over, within ``window`` positions (None: the whole prefix)."""
+    if window is None:
+        return (seq + 1) / 2
+    return sum(min(t, window) for t in range(1, seq + 1)) / seq
+
+
+def layer_flops(w: dict, ctx: float) -> float:
+    """Forward FLOPs of one token through one dense layer of widths
+    ``w``: its matmuls, and attention (scores and values) over ``ctx``
+    positions."""
+    matmuls = sum(2 * s[0] * s[1] for s, _ in layer_shapes(w).values() if len(s) == 2)
+    return matmuls + 4 * w["n_heads"] * w["head_dim"] * ctx
+
+
+def make_layer(key, w: dict, layer, ids: dict = _LEAF_IDS) -> dict:
+    """One dense layer's f32 weights, flat by leaf name (``layer`` may
+    be traced), each leaf drawn under its id in ``ids``."""
+    return {n: leaf(key, ids[n], layer, s, c) for n, (s, c) in layer_shapes(w).items()}
 
 
 def make_embed(key, arch: dict) -> jax.Array:
@@ -75,12 +114,8 @@ def make_head(key, arch: dict) -> tuple:
             _leaf(key, "lm_head", 0, (d, arch["vocab"]), d ** -0.5))
 
 
-def _stacked_layers(key, w: dict, n: int) -> dict:
-    return jax.vmap(lambda i: make_layer(key, w, i))(jnp.arange(n))
-
-
 def nest(flat: dict) -> dict:
-    """Flat leaf names -> the program's block layout."""
+    """Flat leaf names of a dense layer -> the program's block layout."""
     return {
         "ln1": flat["ln1"],
         "mixer": {k: flat[k] for k in ("wq", "wk", "wv", "wo")},
@@ -96,16 +131,19 @@ def make_rest(key, arch: dict) -> dict:
     return {"embed": make_embed(key, arch), "final_norm": final_norm, "lm_head": head}
 
 
-def make_adapter(key, arch: dict, adapter: dict) -> dict:
+def make_adapter(key, arch: dict, adapter: dict, side: tuple) -> dict:
     """The side network's initial f32 state in the program's tree
-    layout. ``up`` is random, not zero, so every leaf has a gradient
-    from the first step on."""
-    n, d, da = arch["n_layers"], arch["d_model"], adapter["d_model"]
+    layout. ``side`` is (taps, windows): ``taps`` backbone taps, and per
+    tap one dense block for each entry of ``windows`` (the j-th block
+    after tap i is drawn as layer ``j * taps + i``). ``up`` is random,
+    not zero, so every leaf has a gradient from the first step on."""
+    (n, windows), d, da = side, arch["d_model"], adapter["d_model"]
     return {
         "downs": jax.vmap(lambda i: _leaf(key, "downs", i, (d, da), d ** -0.5))(
             jnp.arange(n + 1)),
         "lambda": jnp.full((n,), 0.5, jnp.float32),
-        "blocks": [nest(_stacked_layers(key, adapter, n))],
+        "blocks": [nest(jax.vmap(lambda i: make_layer(key, adapter, i))(j * n + jnp.arange(n)))
+                   for j in range(len(windows))],
         "up": _leaf(key, "up", 0, (da, d), da ** -0.5),
         "out_norm": _leaf(key, "out_norm", 0, (da,), NORM_SCALE),
     }
