@@ -111,38 +111,52 @@ class _CTensor:
         return self.data.nbytes + (0 if self.scale is None else self.scale.nbytes)
 
 
-def _compress(x, policy: str, own: bool = False, orig_last: Optional[int] = None) -> _CTensor:
-    """``own=True`` guarantees the payload owns its buffer: a same-dtype
-    conversion is a no-copy view, and an entry holding a view of e.g. one
-    row of a (B,S,d) batch array would pin the whole batch in RAM — the
-    byte budget would no longer bound real memory.
-
-    ``x`` may already BE storage form: an int8 ``{"q", "scale"}`` dict as
-    emitted at the tap site by the pallas OpSet (``emit_tap``). It is
-    adopted as-is — no recompress, no f32 round-trip — provided the
-    policy is int8 and ``orig_last`` names the unpadded feature width."""
+def _storage_form(x, policy: str, orig_last: Optional[int] = None):
+    """``(part, orig_last)``: ``x`` in the form the fetch copies to the
+    host, where it stays an array of whatever kind it came as. An int8
+    ``{"q", "scale"}`` dict, as emitted at the tap site by the pallas
+    OpSet (``emit_tap``), is adopted as-is (no recompress, no f32
+    round-trip) provided the policy is int8; ``orig_last`` then names the
+    unpadded feature width. Other int8 input is quantized here, with jnp
+    (on the device for a device array). f32/bf16 parts are cast on the
+    host after the fetch (:func:`_host_ct`)."""
     if isinstance(x, dict):
         if policy != "int8":
             raise ValueError(
                 f"storage-form (q/scale) tap requires the int8 policy, got {policy!r}"
             )
-        q = np.asarray(x["q"])
-        scale = np.asarray(x["scale"])
-        last = q.shape[-1] if orig_last is None else orig_last
-        return _CTensor("int8", q, scale, last, q.shape[-1] // scale.shape[-1])
-    x = np.asarray(x)
-    if policy in ("f32", "bf16"):
-        target = np.float32 if policy == "f32" else ml_dtypes.bfloat16
-        data = np.asarray(x, target)
-        if own and (data is x or data.base is not None):
-            data = data.copy()
-        return _CTensor(policy, data, None, x.shape[-1])
+        return x, x["q"].shape[-1] if orig_last is None else orig_last
     if policy == "int8":
         qt = quantize(jnp.asarray(x, jnp.float32), bits=8, block=_INT8_BLOCK)
-        return _CTensor(
-            "int8", np.asarray(qt.q), np.asarray(qt.scale), qt.orig_last, qt.block
-        )
+        return {"q": qt.q, "scale": qt.scale}, qt.orig_last
+    if policy in ("f32", "bf16"):
+        return x, x.shape[-1]
     raise ValueError(f"compress must be one of {COMPRESS_POLICIES}, got {policy!r}")
+
+
+def _host_ct(part, policy: str, orig_last: int) -> _CTensor:
+    """The host tensor of a :func:`_storage_form` part: int8 dicts as
+    fetched (the block follows from the shapes), f32/bf16 arrays cast to
+    the policy's dtype."""
+    if isinstance(part, dict):
+        q, scale = np.asarray(part["q"]), np.asarray(part["scale"])
+        return _CTensor("int8", q, scale, orig_last, q.shape[-1] // scale.shape[-1])
+    target = np.float32 if policy == "f32" else ml_dtypes.bfloat16
+    return _CTensor(policy, np.asarray(part, target), None, orig_last)
+
+
+def _compress(x, policy: str, own: bool = False, orig_last: Optional[int] = None) -> _CTensor:
+    """``own=True`` guarantees the payload owns its buffer: a same-dtype
+    conversion is a no-copy view, and an entry holding a view of e.g. one
+    row of a (B,S,d) batch array would pin the whole batch in RAM — the
+    byte budget would no longer bound real memory."""
+    part, last = _storage_form(x, policy, orig_last)
+    if not isinstance(part, dict):
+        part = np.asarray(part)
+    ct = _host_ct(part, policy, last)
+    if own and ct.scale is None and (ct.data is part or ct.data.base is not None):
+        ct.data = ct.data.copy()
+    return ct
 
 
 def _ct_index(ct: _CTensor, idx) -> _CTensor:
@@ -212,6 +226,33 @@ def _join_on_device(seqs):
     return tuple(stack(col, 1 if i == 1 else 0) for i, col in enumerate(zip(*seqs)))
 
 
+@jax.jit
+def _split_on_device(parts):
+    """A batch's sequences, each part a device array of its own: the
+    device twin of :func:`_ct_index` and the inverse of
+    :func:`_join_on_device` — b0 and b_final split on axis 0, taps
+    (n_p, B, S, d) on axis 1, q/scale dicts leaf by leaf. A slice only
+    copies, so each piece equals the host slice bit for bit, and an entry
+    made from it holds its own sequence's bytes and no more."""
+    def take(i):
+        return tuple(jax.tree.map(lambda x: x[:, i] if axis else x[i], part)
+                     for axis, part in zip((0, 1, 0), parts))
+
+    return [take(i) for i in range(jax.tree.leaves(parts[0])[0].shape[0])]
+
+
+@dataclass
+class _Pending:
+    """The one batch whose host copy is in flight: its keys, each
+    sequence's storage-form parts on the device (``_split_on_device``,
+    every piece's device→host copy started) and each part's unpadded
+    feature width."""
+
+    keys: list
+    seqs: list
+    orig_lasts: tuple
+
+
 @dataclass
 class CacheEntry:
     """One sequence's cached activations: (b0, taps[, b_final])."""
@@ -273,6 +314,14 @@ class ActivationCache:
     redistribution step. Entries are compressed per ``compress`` at put
     time; the byte budget covers compressed bytes. All mutating paths
     hold a lock so :class:`CachePrefetcher` can read from its own thread.
+
+    At most one batch is in flight: :meth:`put_batch` with device arrays
+    returns before they reach the host, and the next ``put_batch``
+    completes them. Every read is a barrier that completes the batch in
+    flight first (``get``/``get_batch`` of a key it may hold, ``covers``,
+    ``in``, ``len``, ``keys``, ``nbytes``, ``flush``, ``save_manifest``,
+    ``clear``, ``put``), so a put is visible to every read. Host span:
+    ``pac.cache.drain`` (``nbytes``) around a barrier's completion.
     """
 
     budget_bytes: int = 2 << 30
@@ -285,6 +334,7 @@ class ActivationCache:
     hits: int = 0
     misses: int = 0
     _lock: threading.RLock = field(default_factory=threading.RLock, repr=False)
+    _pending: Optional[_Pending] = field(default=None, repr=False)
 
     def __post_init__(self):
         if self.compress not in COMPRESS_POLICIES:
@@ -293,27 +343,65 @@ class ActivationCache:
             )
 
     def __contains__(self, key: int) -> bool:
-        return key in self._ram or key in self._disk
+        with self._lock:
+            self._drain()
+            return key in self._ram or key in self._disk
 
     def __len__(self) -> int:
         # a promoted entry keeps its (clean) disk copy — count keys once
-        return len(self._ram.keys() | self._disk.keys())
+        return len(self.keys())
 
     @property
     def nbytes(self) -> int:
-        return self._ram_bytes
+        with self._lock:
+            self._drain()
+            return self._ram_bytes
 
     def keys(self) -> Set[int]:
-        return self._ram.keys() | self._disk.keys()
+        with self._lock:
+            self._drain()
+            return self._ram.keys() | self._disk.keys()
 
     def covers(self, keys, with_final: bool = False) -> bool:
         """True when every key is resident (RAM or disk) — the gate for
         running an epoch through the prefetcher instead of the forward."""
         with self._lock:
+            held = self.keys()
             return all(
-                int(k) in self and not (with_final and int(k) in self._final_absent)
+                int(k) in held and not (with_final and int(k) in self._final_absent)
                 for k in keys
             )
+
+    # -- the batch in flight -------------------------------------------------
+
+    def _complete(self) -> int:
+        """Land the batch in flight, if any, and return its stored bytes:
+        wait for each piece's host copy (``pac.cache.fetch``, ``nbytes``)
+        and store every sequence's entry as fetched, with no host copy
+        (``pac.cache.store``). The caller holds the lock."""
+        pending, self._pending = self._pending, None
+        if pending is None:
+            return 0
+        with TraceAnnotation("pac.cache.fetch") as fetch_span:
+            entries = [
+                CacheEntry(*(_host_ct(part, self.compress, last)
+                             for part, last in zip(seq, pending.orig_lasts)))
+                for seq in pending.seqs
+            ]
+            nbytes = sum(e.nbytes for e in entries)
+            fetch_span.set_metadata(nbytes=nbytes)
+        with TraceAnnotation("pac.cache.store"):
+            for k, entry in zip(pending.keys, entries):
+                self._put_entry(k, entry)
+        return nbytes
+
+    def _drain(self) -> None:
+        """The read barrier: complete the batch in flight under
+        ``pac.cache.drain`` (``nbytes``: its stored bytes)."""
+        with self._lock:
+            if self._pending is not None:
+                with TraceAnnotation("pac.cache.drain") as drain_span:
+                    drain_span.set_metadata(nbytes=self._complete())
 
     # -- writes ------------------------------------------------------------
 
@@ -324,6 +412,7 @@ class ActivationCache:
             None if b_final is None else _compress(b_final, self.compress, own=True),
         )
         with self._lock:
+            self._drain()
             self._put_entry(key, entry)
 
     def _put_entry(self, key: int, entry: CacheEntry) -> None:
@@ -386,6 +475,7 @@ class ActivationCache:
         if not self.spill_dir:
             raise ValueError("flush() requires a spill_dir")
         with self._lock:
+            self._drain()
             for k, entry in self._ram.items():
                 if k not in self._disk:
                     self._spill(k, entry)
@@ -394,6 +484,14 @@ class ActivationCache:
 
     def _get_entry(self, key: int, need_final: bool) -> Optional[CacheEntry]:
         with self._lock:
+            # a key held nowhere, the batch in flight included, misses
+            # before and after that batch lands: so a step's lookup of
+            # its new keys does not wait for the previous step's fetch
+            pending = self._pending
+            if pending is not None and (
+                    key in self._ram or key in self._disk
+                    or key in pending.keys):
+                self._drain()
             if need_final and key in self._final_absent:
                 # present but incomplete for this request — the caller
                 # re-forwards and re-puts with b_final (replacing the entry)
@@ -444,38 +542,50 @@ class ActivationCache:
 
     def put_batch(self, keys, b0, taps, b_final=None,
                   orig_last: Optional[int] = None) -> None:
-        """b0: (B,S,d); taps: (n_p,B,S,d); b_final: (B,S,d) — device
-        arrays from epoch 1 (one device→host gather each, not B). Each
-        may instead arrive already in storage form — the int8
-        ``{"q", "scale"}`` dict a pallas OpSet emits at the tap site —
-        and is adopted without recompression (``orig_last`` = the
-        unpadded feature width, d).
-
-        Compression runs once on the whole batch array and per-sequence
-        entries are sliced (with copies) out of the result — block-wise
+        """b0: (B,S,d); taps: (n_p,B,S,d); b_final: (B,S,d). Each may
+        arrive already in storage form — the int8 ``{"q", "scale"}`` dict
+        a pallas OpSet emits at the tap site — and is adopted without
+        recompression (``orig_last`` = the unpadded feature width, d).
+        Compression runs once on the whole batch array: block-wise
         quantization along the last axis makes the payloads bit-identical
         to per-sequence compression at 1/B the dispatch overhead.
 
+        Device arrays (epoch 1's outputs) are split into their sequences
+        on the device (``_split_on_device``), each piece's device→host
+        copy is started, and the batch is left in flight: this returns
+        without waiting, so the copy runs behind the next step. The next
+        ``put_batch`` completes it — the entries land as fetched, with no
+        host copy — and any read completes it first (the class's
+        barrier), so at most one batch is in flight and a put is visible
+        to every read. Host arrays have no copy to hide: their entries
+        are sliced (with copies) out of the batch and stored here.
+
         Host spans: ``pac.cache.put_batch`` holds ``pac.cache.fetch``
-        (the device→host copy, and compression where the parts are not
-        in storage form yet) and ``pac.cache.store`` (slicing and
-        storing the entries, eviction and spill included); the fetch
-        carries ``nbytes``, the stored batch's bytes."""
+        (waiting for the host copy of the batch it completes, or for host
+        arrays, compression) and ``pac.cache.store`` (storing the entries,
+        eviction and spill included); the fetch carries ``nbytes``, the
+        stored batch's bytes."""
+        parts = (b0, taps) if b_final is None else (b0, taps, b_final)
         with TraceAnnotation("pac.cache.put_batch"):
+            if all(isinstance(x, jax.Array) for x in jax.tree.leaves(parts)):
+                forms = [_storage_form(p, self.compress, orig_last) for p in parts]
+                seqs = _split_on_device(tuple(part for part, _ in forms))
+                for piece in jax.tree.leaves(seqs):
+                    piece.copy_to_host_async()
+                with self._lock:
+                    self._complete()
+                    self._pending = _Pending(
+                        [int(k) for k in keys], seqs, tuple(last for _, last in forms))
+                return
+            with self._lock:
+                self._complete()
             with TraceAnnotation("pac.cache.fetch") as fetch_span:
-                cb0 = _compress(b0, self.compress, orig_last=orig_last)
-                ctaps = _compress(taps, self.compress, orig_last=orig_last)
-                cbf = None if b_final is None else _compress(
-                    b_final, self.compress, orig_last=orig_last)
-                fetch_span.set_metadata(
-                    nbytes=cb0.nbytes + ctaps.nbytes + (0 if cbf is None else cbf.nbytes))
+                cts = [_compress(p, self.compress, orig_last=orig_last) for p in parts]
+                fetch_span.set_metadata(nbytes=sum(ct.nbytes for ct in cts))
             with TraceAnnotation("pac.cache.store"):
                 for i, k in enumerate(keys):
-                    entry = CacheEntry(
-                        _ct_index(cb0, i),
-                        _ct_index(ctaps, (slice(None), i)),
-                        None if cbf is None else _ct_index(cbf, i),
-                    )
+                    entry = CacheEntry(*(_ct_index(ct, (slice(None), i) if j == 1 else i)
+                                         for j, ct in enumerate(cts)))
                     with self._lock:
                         self._put_entry(int(k), entry)
 
@@ -502,7 +612,10 @@ class ActivationCache:
         return b0, taps, bf
 
     def clear(self) -> None:
+        """Drop every entry and spill file; the batch in flight is
+        fetched and stored first, so every put batch reaches the host."""
         with self._lock:
+            self._drain()
             for path in self._disk.values():
                 try:
                     os.remove(path)

@@ -2,10 +2,12 @@
 plus the v2 surface: compressed entries, folded b_final, async prefetch,
 and cross-run persistence."""
 
+import json
 import os
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -675,3 +677,179 @@ def test_prefetcher_reshard_close_reopen_mid_epoch():
     for w, g in zip(want, got):
         for a, b in zip(w, g):
             np.testing.assert_array_equal(a, b)
+
+
+# ---------------------------------------------------------------------------
+# put_batch with device arrays: split on the device, fetched behind the
+# next put, completed by every read
+# ---------------------------------------------------------------------------
+
+
+def _batches(policy, with_final, d=200):
+    """Two host batches (4 sequences, then a short last one of 3) in the
+    form put_batch takes for ``policy``: f32 arrays, or for
+    "int8-storage" the {"q", "scale"} dicts a pallas OpSet emits (d is
+    not a multiple of the block, so q is padded and orig_last matters)."""
+    from repro.core.quantization import quantize
+
+    out = []
+    for seed, B in ((0, 4), (1, 3)):
+        rng = np.random.RandomState(seed)
+        parts = [rng.randn(B, 8, d), rng.randn(2, B, 8, d), rng.randn(B, 8, d)]
+        parts = [p.astype(np.float32) for p in parts[:3 if with_final else 2]]
+        if policy == "int8-storage":
+            parts = [{"q": np.asarray(qt.q), "scale": np.asarray(qt.scale)}
+                     for qt in (quantize(jnp.asarray(p), bits=8, block=128) for p in parts)]
+        out.append(parts)
+    return out
+
+
+def _put_all(cache, batches, to_device, d=200):
+    start = 0
+    for parts in batches:
+        B = jax.tree.leaves(parts[0])[0].shape[0]
+        if to_device:
+            parts = jax.device_put(parts)
+        cache.put_batch(list(range(start, start + B)), *parts, orig_last=d)
+        start += B
+    return list(range(start))
+
+
+def _same_entry(a, b):
+    for (na, ca), (nb, cb) in zip(a.parts(), b.parts(), strict=True):
+        assert na == nb
+        assert (ca.policy, ca.orig_last, ca.block) == (cb.policy, cb.orig_last, cb.block)
+        assert (ca.data.dtype, ca.data.shape) == (cb.data.dtype, cb.data.shape)
+        assert ca.data.tobytes() == cb.data.tobytes()
+        assert (ca.scale is None) == (cb.scale is None)
+        if ca.scale is not None:
+            assert ca.scale.tobytes() == cb.scale.tobytes()
+
+
+@pytest.mark.parametrize("with_final", [False, True], ids=["no-final", "final"])
+@pytest.mark.parametrize("policy", ["f32", "bf16", "int8", "int8-storage"])
+def test_put_batch_from_device_matches_host(policy, with_final):
+    """Device arrays are split on the device and left in flight; once
+    landed, every entry equals the host path's bit for bit."""
+    compress = "int8" if policy == "int8-storage" else policy
+    batches = _batches(policy, with_final)
+    host = ActivationCache(budget_bytes=1 << 26, compress=compress)
+    keys = _put_all(host, batches, to_device=False)
+    assert host._pending is None
+    dev = ActivationCache(budget_bytes=1 << 26, compress=compress)
+    _put_all(dev, batches, to_device=True)
+    assert dev._pending is not None and dev._pending.keys == keys[4:]
+    assert dev.nbytes == host.nbytes and dev._pending is None
+    for k in keys:
+        _same_entry(dev._ram[k], host._ram[k])
+
+
+def _landed_like_host(cache, keys):
+    want = ActivationCache(budget_bytes=1 << 26, compress=cache.compress)
+    _put_all(want, _batches("int8", True), to_device=False)
+    for k in keys:
+        _same_entry(cache._ram[k], want._ram[k])
+
+
+def _manifest_keys(cache, tmp_path):
+    cache.flush()
+    with open(cache.save_manifest({"run": 1})) as f:
+        entries = json.load(f)["entries"]
+    assert all(os.path.exists(tmp_path / e["file"]) for e in entries.values())
+    return sorted(int(k) for k in entries)
+
+
+_READS = {
+    "get": lambda c, keys, _: c.get(keys[-1], with_final=True) is not None,
+    "get_batch": lambda c, keys, _: c.get_batch(keys, with_final=True) is not None,
+    "covers": lambda c, keys, _: c.covers(keys, with_final=True),
+    "in": lambda c, keys, _: keys[-1] in c,
+    "len": lambda c, keys, _: len(c) == len(keys),
+    "keys": lambda c, keys, _: c.keys() == set(keys),
+    "nbytes": lambda c, keys, _: c.nbytes == sum(e.nbytes for e in c._ram.values()) > 0,
+    "flush+save_manifest": lambda c, keys, tmp: _manifest_keys(c, tmp) == keys,
+    "prefetcher": lambda c, keys, _: all(
+        b is not None for b in CachePrefetcher(c, [np.array(keys)], to_device=False)),
+}
+
+
+@pytest.mark.parametrize("read", sorted(_READS))
+def test_every_read_sees_the_batch_in_flight(read, tmp_path):
+    """Each read is a barrier: the batch put last, still in flight, is
+    landed first and seen by the read — a prefetcher's worker reading
+    right after the put included."""
+    cache = ActivationCache(budget_bytes=1 << 26, compress="int8",
+                            spill_dir=str(tmp_path))
+    keys = _put_all(cache, _batches("int8", True), to_device=True)
+    assert cache._pending is not None
+    assert _READS[read](cache, keys, tmp_path)
+    assert cache._pending is None
+    _landed_like_host(cache, keys)
+
+
+def test_lookup_of_new_keys_leaves_the_batch_in_flight():
+    """A key held nowhere misses whether or not the batch in flight has
+    landed, so its lookup does not wait for the fetch (a capture step
+    looks up its own new keys before it runs); a key of the batch, or
+    one already held, lands the batch first."""
+    cache = ActivationCache(budget_bytes=1 << 26, compress="int8")
+    first, second = _batches("int8", True)
+    cache.put_batch([0, 1, 2, 3], *jax.device_put(first), orig_last=200)
+    assert cache.get_batch([10, 11], with_final=True) is None
+    assert cache.misses == 2 and cache._pending is not None
+    assert cache.get(2, with_final=True) is not None and cache._pending is None
+    cache.put_batch([4, 5, 6], *jax.device_put(second), orig_last=200)
+    assert cache.get(0) is not None and cache._pending is None
+
+
+def test_clear_lands_then_drops_the_batch_in_flight(tmp_path):
+    """clear() after a device put leaves no entry, no byte and no spill
+    file, though landing the batch spilled part of it."""
+    cache = ActivationCache(budget_bytes=1 << 26, compress="int8",
+                            spill_dir=str(tmp_path))
+    sizer = ActivationCache(budget_bytes=1 << 26, compress="int8")
+    _put_all(sizer, _batches("int8", True), to_device=False)
+    cache.budget_bytes = 2 * sizer.nbytes // 7  # two of the seven entries
+    _put_all(cache, _batches("int8", True), to_device=True)
+    cache.clear()
+    assert cache._pending is None
+    assert len(cache) == 0 and cache.nbytes == 0 and not cache._final_absent
+    assert os.listdir(tmp_path) == []
+
+
+def _held_bytes(a):
+    """Bytes of the buffer an array keeps alive through its base chain."""
+    while isinstance(a.base, np.ndarray):
+        a = a.base
+    return a.nbytes if a.base is None else memoryview(a.base).nbytes
+
+
+@pytest.mark.parametrize("policy", ["f32", "int8"])
+def test_device_entries_hold_only_their_own_sequence(policy):
+    """The device twin of test_entries_do_not_alias_the_batch_array: an
+    entry landed from device arrays holds its own sequence's bytes and
+    no more, with no host copy of the batch behind it."""
+    cache = ActivationCache(budget_bytes=1 << 26, compress=policy)
+    (parts, _) = _batches("f32", True)
+    dev = jax.device_put(parts)
+    cache.put_batch([0, 1, 2, 3], *dev)
+    assert len(cache) == 4
+    batch_views = [np.asarray(p) for p in dev]
+    for entry in cache._ram.values():
+        for _, ct in entry.parts():
+            for a in (ct.data, ct.scale):
+                if a is None:
+                    continue
+                assert _held_bytes(a) == a.nbytes
+                assert not any(np.shares_memory(a, v) for v in batch_views)
+
+
+@pytest.mark.parametrize("to_device", [False, True], ids=["host", "device"])
+def test_storage_form_refused_before_anything_is_deferred(to_device):
+    """A {"q", "scale"} dict under a non-int8 policy raises in put_batch
+    itself, on both paths, and leaves nothing stored or in flight."""
+    (parts, _) = _batches("int8-storage", True)
+    cache = ActivationCache(budget_bytes=1 << 26, compress="f32")
+    with pytest.raises(ValueError, match="int8 policy"):
+        cache.put_batch([0, 1, 2, 3], *(jax.device_put(parts) if to_device else parts))
+    assert cache._pending is None and len(cache) == 0

@@ -143,3 +143,24 @@ def test_kernel_compiles_for_v5e(one_chip, name):
                if 'custom_call_target="tpu_custom_call"' in ln]
     for kernel in KERNEL_NAMES.get(name, []):
         assert any(re.fullmatch(rf"%{kernel}\.\d+", c) for c in customs), (kernel, customs)
+
+
+def test_cache_split_compiles_for_v5e(one_chip):
+    """``ActivationCache.put_batch``'s device split at the capture cell's
+    shapes (batch 4 × 1,024 tokens, 24 taps, d 2,048, int8 storage form)
+    lowers for the chip: set-up's first put compiles it, not the window."""
+    from repro.core.activation_cache import _split_on_device
+
+    B, S, NP = 4, 1024, 24
+
+    def form(*lead):
+        return {"q": jax.ShapeDtypeStruct(lead + (S, D), i8, sharding=one_chip),
+                "scale": jax.ShapeDtypeStruct(lead + (S, D // 128), f32, sharding=one_chip)}
+
+    parts = (form(B), form(NP, B), form(B))
+    _split_on_device.lower(parts).compile()
+    seqs = jax.eval_shape(_split_on_device, parts)
+    assert len(seqs) == B
+    for b0, taps, bf in seqs:
+        assert b0["q"].shape == bf["q"].shape == (S, D)
+        assert taps["q"].shape == (NP, S, D) and taps["scale"].shape == (NP, S, D // 128)
