@@ -66,8 +66,7 @@ N_MICRO = 2
 
 def build_demo_plan():
     """The 10-period demo model and its RAGGED Env.B plan (pure Python —
-    safe before any JAX backend init). Also the workload
-    ``benchmarks/bench_heterogeneous.py --executed`` measures."""
+    safe before any JAX backend init)."""
     from repro.configs.base import ArchConfig, LayerSpec, register
     from repro.core.planner import (
         HybridParallelismPlanner,

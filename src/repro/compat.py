@@ -11,7 +11,6 @@ pass ``axis_types=`` / ``check_vma=``, or import ``shard_map``.
 * ``shard_map(f, mesh, in_specs, out_specs, check_rep=...)`` —
   ``jax.shard_map``; ``check_rep`` is its ``check_vma``.
 * ``tree_map`` / ``tree_map_with_path`` — the pytree helpers.
-* ``ambient_mesh()`` — the mesh set by an enclosing ``jax.set_mesh``.
 * ``force_host_device_count(n)`` — the ``XLA_FLAGS`` knob for faked
   host devices (must run before the first backend initialisation).
 * ``default_cache_dir()`` / ``enable_compilation_cache()`` — JAX's
@@ -32,7 +31,6 @@ __all__ = [
     "shard_map",
     "tree_map",
     "tree_map_with_path",
-    "ambient_mesh",
     "force_host_device_count",
     "default_cache_dir",
     "enable_compilation_cache",
@@ -66,13 +64,6 @@ def shard_map(f, mesh, in_specs, out_specs, check_rep: Optional[bool] = None):
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
 
 
-def ambient_mesh():
-    """The mesh set by an enclosing ``jax.set_mesh``; ``None`` when no
-    mesh is set — callers treat that as the single-device regime."""
-    m = jax.sharding.get_abstract_mesh()
-    return None if m.empty else m
-
-
 # ---------------------------------------------------------------------------
 # Pytree helpers
 # ---------------------------------------------------------------------------
@@ -87,7 +78,7 @@ tree_map_with_path = jax.tree_util.tree_map_with_path
 
 
 def force_host_device_count(n: int) -> None:
-    """Fake ``n`` host-platform devices (dry runs / subprocess tests).
+    """Fake ``n`` host-platform devices (multi-device runs on the CPU).
 
     Must be called before the first JAX backend initialisation — the
     device count locks when the backend comes up, not at ``import jax``.

@@ -8,9 +8,7 @@ hyper-parameters (source cited in the config).
 import importlib
 
 from repro.configs.base import (  # noqa: F401
-    INPUT_SHAPES,
     ArchConfig,
-    InputShape,
     LayerSpec,
     MoESpec,
     get_arch,

@@ -67,13 +67,6 @@ def lora_delta(lp, x, which: str, rank_scale):
 
 def apply_block_lora(p, lp, x, cfg, spec, positions, rank_scale):
     """Block forward with LoRA deltas on the q/v-ish projections."""
-    from repro.core import psharding
-
-    # same §Perf-iter-2 treatment as the shared apply_block: gather the
-    # layer's weight slice (TP-only) so backward doesn't all-reduce
-    # activations over `data` (missing this cost LoRA 6× the collective
-    # volume of full FT on the production mesh — measured)
-    p = psharding.gather_for_compute(p)
     p = maybe_dequantize_tree(p)
     h = rms_norm(x, p["ln1"], cfg.norm_eps)
     if spec.kind == "attn":

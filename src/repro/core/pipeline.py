@@ -8,7 +8,7 @@ Three layers:
 2. **Simulator** — ``simulate_plan`` replays a
    :class:`~repro.core.planner.Plan` through a discrete-event model
    (compute, inter-stage links, AllReduce) and returns the per-minibatch
-   timeline; this is what the Fig. 12/16 benchmarks sweep.
+   timeline (the modelled side of Figs. 12/16).
 3. **Runtime** — ``pipeline_apply`` runs a *real* SPMD pipeline over a
    ``stage`` mesh axis with ``shard_map`` + ``ppermute`` (GPipe-style
    rotation, autodiff straight through the collective). Since PR 2 this

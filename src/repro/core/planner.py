@@ -17,8 +17,8 @@ Faithful implementation of the paper's two nested dynamic programs:
 The planner is offline and hardware-agnostic: it consumes per-layer
 ``LayerCost`` records (analytic FLOPs/bytes here; measured times on a
 real testbed) and ``DeviceProfile``s. Used by the edge-regime pipeline
-runtime (`repro.core.pipeline`), the paper-table benchmarks, and the
-scalability/heterogeneity studies (Figs. 12, 16, 17).
+runtime (`repro.core.pipeline`), the session's plan (`repro.runtime`)
+and the fleet's chunk dispatch (`repro.fleet`).
 """
 
 from __future__ import annotations

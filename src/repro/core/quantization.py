@@ -3,8 +3,9 @@
 Implements the paper's Eq. (1)/(2): weights are stored in a low-bit
 integer format (INT8, or packed INT4) with one f32 scale per contiguous
 block of ``block`` elements along the **last** axis — keeping the original
-dimension structure so GSPMD sharding rules written for the f32 parameter
-apply unchanged to the quantized storage.
+dimension structure, so code written against the f32 parameter's shape
+(its sharding, the backbone scan's slicing) applies unchanged to the
+quantized storage.
 
 The storage/compute split follows the paper's Fig. 8 (and QLoRA): storage
 dtype INT8/INT4, compute dtype f32/bf16 — ``dequantize`` happens at use,

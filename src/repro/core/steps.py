@@ -178,17 +178,17 @@ def dp_cached_train_step(
     via an explicit shard_map — the DP twin of :func:`pac_cached_train_step`
     for the Pallas path (whose ``pallas_call``s GSPMD cannot repartition;
     the ref path can instead be jitted with
-    ``launch.sharding.cached_step_shardings``).
+    ``core.mesh.cached_step_shardings``).
 
     Per shard: local (num, den) CE parts from the fused loss, psum'd over
     ``batch_axes`` before the division (exact global mean); adapter grads
     pmean'd (the psum's transpose re-sums the replicated cotangent, so the
     mean removes the axes× factor — same argument as the pipeline step).
     The update is replicated. ``batch_axes`` must shard the batch dim of
-    every cached entry (use ``launch.sharding.cached_batch_axes``).
+    every cached entry (use ``core.mesh.cached_batch_axes``).
     """
     from repro.kernels.cached_step import cached_loss_parts
-    from repro.launch.sharding import batch_specs
+    from repro.core.mesh import batch_specs
 
     axes = tuple(batch_axes)
 
@@ -208,10 +208,7 @@ def dp_cached_train_step(
         grads = jax.tree.map(lambda g: jax.lax.pmean(g, axes), grads)
         return loss, grads
 
-    # shard_seq=False: the psums above reduce over batch_axes only, so a
-    # `model`-axis sequence split of the entries would silently drop
-    # every other shard's tokens from the loss
-    cspecs = batch_specs(cached_batch, mesh, batch_axes=axes, shard_seq=False)
+    cspecs = batch_specs(cached_batch, axes)
     fn = shard_map(
         spmd,
         mesh=mesh,

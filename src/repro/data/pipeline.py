@@ -6,7 +6,7 @@ what makes the activation cache pay off. We provide:
 
 * ``SyntheticPersonalCorpus`` — a deterministic synthetic corpus with a
   learnable structure (Zipf-ish unigram mixture per "intent" class, with
-  class-dependent transition rules) so fine-tuning quality benchmarks
+  class-dependent transition rules) so fine-tuning quality checks
   (paper Table VI analogue) have a real signal to fit.
 * ``glue_like_task`` — sequence-classification-style corpora mirroring
   MRPC/STS-B/SST-2/QNLI sizes.

@@ -97,12 +97,11 @@ class SessionJob:
         from repro.core.planner import plan_pure_dp
 
         if self._costs is None:
-            from repro.launch.costs import resolve_cost_model
+            from repro.core.planner import period_costs
 
-            self._costs = resolve_cost_model(
-                False, micro_batch=self.chunk, quant_bits=self.spec.quant,
-            ).period_costs(self.spec.arch_config(), "pac_cached",
-                           seq_len=self.spec.seq)
+            self._costs = period_costs(
+                self.spec.arch_config(), "pac_cached", seq_len=self.spec.seq,
+                quant_bits=self.spec.quant)
         plan = plan_pure_dp(self._costs, list(profiles), self.n_chunks, 1)
         if plan is None:
             return None

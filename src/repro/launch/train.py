@@ -35,9 +35,7 @@ boundaries, and the micro-batch count; the mesh is built from the plan
 and the hybrid step executes those exact boundaries (ragged stages run
 padded slabs with masked identity periods). ``--plan <file.json>``
 replays a plan saved earlier with ``--save-plan`` (`Plan.to_json`
-round-trip). ``--calibrate`` prices one real lowered period with the
-trip-count-aware HLO cost model and feeds the measured ``LayerCost``s to
-the planner instead of the analytic ones.
+round-trip).
 
     # plan-driven: Alg. 1 chooses stages/boundaries/micro, trainer executes it
     PYTHONPATH=src python -m repro.launch.train --arch internlm2-1.8b \
@@ -132,9 +130,6 @@ def main() -> None:
                          "max(dp*stages, 4); the mesh uses dp*stages <= pool)")
     ap.add_argument("--save-plan", default=None,
                     help="write the executed plan as JSON for later replay")
-    ap.add_argument("--calibrate", action="store_true",
-                    help="price one lowered period with the HLO cost model "
-                         "and plan from measured LayerCosts")
     ap.add_argument("--kernels", default="ref", choices=["ref", "pallas"],
                     help="compute path for epoch 1 AND the cached epochs: "
                          "'ref' = dense jnp oracle; 'pallas' = OpSet "

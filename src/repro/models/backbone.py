@@ -5,8 +5,8 @@ One configurable decoder covers all 10 assigned architectures: an
 heterogeneous :class:`LayerSpec`s (attention / mamba / mLSTM / sLSTM,
 dense-FFN / MoE / no-FFN) which is tiled ``n_periods`` times. Parameters
 are stacked over periods and the forward pass is a ``jax.lax.scan`` over
-the stack, so the lowered HLO is depth-independent (critical for the
-512-device dry-run compile budget).
+the stack, so the lowered HLO is depth-independent: compile time does
+not grow with the layer count.
 
 The forward pass optionally emits **taps** — the hidden state after every
 period — which are exactly the invariant activations ``b_i`` the PAC+
@@ -25,7 +25,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core import psharding
 from repro.core.opset import get_opset
 from repro.core.quantization import maybe_dequantize_tree
 from repro.models import ssm
@@ -97,7 +96,7 @@ def init_backbone(rng, cfg, dtype=jnp.float32) -> dict:
 
 
 def abstract_backbone(cfg, dtype=jnp.float32):
-    """ShapeDtypeStruct pytree of the params — no allocation (dry-run path)."""
+    """ShapeDtypeStruct pytree of the params — no allocation."""
     return jax.eval_shape(lambda: init_backbone(jax.random.PRNGKey(0), cfg, dtype))
 
 
@@ -108,11 +107,6 @@ def abstract_backbone(cfg, dtype=jnp.float32):
 
 def apply_block(p, x, cfg, spec, positions, ops=None, return_kv: bool = False):
     ops = ops if ops is not None else _REF_OPS
-    # FSDP weight gather (§Perf iteration 2): replicate this layer's slice
-    # over the data axes so GSPMD all-gathers weights (not activations).
-    # Gather BEFORE preparing — the int8 payload is 4× cheaper to move
-    # (§Perf kimi iter H). No-op outside a `model`-axis mesh.
-    p = psharding.gather_for_compute(p)
     # ref: dequantize the whole block; pallas: matmul weights stay
     # quantized and feed quant_matmul inside ops.matmul
     p = ops.prepare_block(p, spec)
@@ -131,14 +125,13 @@ def apply_block(p, x, cfg, spec, positions, ops=None, return_kv: bool = False):
         mix = ssm.mlstm_forward(p["mixer"], h, cfg)
     elif spec.kind == "slstm":
         mix = ssm.slstm_forward(p["mixer"], h, cfg)
-    x = psharding.constrain_hidden(x + mix)
+    x = x + mix
     if "ffn" in p:
         h = ops.rms_norm(x, p["ln2"], cfg.norm_eps)
         if spec.moe and cfg.moe is not None:
             x = x + moe_forward(p["ffn"], h, cfg.moe)
         else:
             x = x + mlp_forward(p["ffn"], h, ops=ops)
-        x = psharding.constrain_hidden(x)
     if return_kv:
         # (k, v) post-rope for attention blocks, None otherwise — the
         # paged-serving prefill scatters these into the KV page pool
@@ -223,12 +216,11 @@ def backbone_logits(params, cfg, batch: dict):
 def cross_entropy(logits: jax.Array, labels: jax.Array, ignore: int = -100):
     """Mean CE over non-ignored positions. logits (B,S,V), labels (B,S).
 
-    Implemented as a one-hot contraction rather than take_along_axis: with
-    the vocab dim sharded over the `model` mesh axis, a gather-by-label
-    would force GSPMD to all-gather the full (B,S,V) logits (~370 GB for
-    internlm2×train_4k — measured in EXPERIMENTS.md §Perf iteration 1).
-    The one-hot product reduces over the sharded vocab locally and
-    all-reduces only (B,S) partials.
+    Implemented as a one-hot contraction rather than take_along_axis: the
+    label pick is then a product and a reduction over the vocab axis, and
+    its gradient a dense (softmax − one-hot) with no scatter. It is the
+    ``ref`` oracle the fused ``lmhead_ce`` path is tested against, so its
+    numerics are pinned.
     """
     num, den = cross_entropy_parts(logits, labels, ignore)
     return num / jnp.maximum(den, 1)

@@ -170,10 +170,9 @@ def _flash_fwd_impl(q, k, v, q_pos, k_pos, causal, window, cap, block_k):
         k = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)))
         k_pos = jnp.pad(k_pos, (0, pad), constant_values=_PAD_POS)
-    # §Perf (internlm2 iter 5): scan over block *indices* and
-    # dynamic-slice K/V in the body — the (nb,B,H,blk,hd) pre-stacked
-    # transpose materialized 2 copies of K/V per layer (2×1.1 GB/layer
-    # measured on internlm2×train_4k).
+    # scan over block *indices* and dynamic-slice K/V in the body: a
+    # pre-stacked (nb,B,H,blk,hd) transpose would hold two more copies
+    # of K/V per layer.
     pb = k_pos.reshape(nb, block_k)
 
     def body(carry, xs):
@@ -233,19 +232,11 @@ def _flash_bwd(causal, window, cap, block_k, res, do):
         mask = _block_mask(q_pos, pj, causal, window)
         s = jnp.where(mask[None, None], s, _NEG_INF)
         p = jnp.exp(s - lse[..., None])  # (B,H,Sq,blk)
-        from repro.core.psharding import constrain_spec
-
-        p = constrain_spec(p, ("batch", None, "model", None))  # as ds below
         dv_j = jnp.einsum("bhqk,bhqd->bhkd", p, do_f)
         dp = jnp.einsum("bhqd,bhkd->bhqk", do_f, vj.astype(jnp.float32))
         ds = p * (dp - delta[..., None])  # d wrt post-cap logits
         ds = _dscores(q, kj, scale, cap, ds)  # includes scale & cap chain
         ds = jnp.where(mask[None, None], ds, 0.0)
-        # keep ds/p row-sharded (q rows live on `model` under sequence
-        # parallelism): the dk/dv contractions then partial-sum + AR the
-        # small (B,H,blk,hd) blocks instead of all-gathering the
-        # score-sized tensors (412 GB/step on internlm2×train_4k, LoRA)
-        ds = constrain_spec(ds, ("batch", None, "model", None))
         dq = dq + jnp.einsum("bhqk,bhkd->bhqd", ds, kj.astype(jnp.float32))
         dk_j = jnp.einsum("bhqk,bhqd->bhkd", ds, q.astype(jnp.float32))
         return dq, (dk_j, dv_j)
@@ -315,17 +306,11 @@ def ref_attention_core(q, k, v, cfg, spec, block_k: int = 1024) -> jax.Array:
     """The jnp attention core on projected/rope'd q,k,v — the `ref`
     OpSet's attention. q: (B,S,H,hd); k,v: (B,S,Hkv,hd) -> (B,S,H·hd).
 
-    §Perf (kimi iters F+G): gather K/V over `model` once *before* the
-    GQA head expansion (n_kv_heads, not n_heads — 8× less traffic on
-    kimi), and run *grouped-head* flash: the n_rep query heads sharing
-    a KV head are folded into the query-row axis, so the repeated KV is
-    never materialized (iter F's repeat cost +29 GB of HBM temp).
+    Grouped-head flash: the n_rep query heads sharing a KV head are
+    folded into the query-row axis, so K/V are read at n_kv_heads and
+    the repeated KV (n_rep× their size) is never materialized.
     """
-    from repro.core.psharding import constrain_spec
-
     B, S, _, _ = q.shape
-    k = constrain_spec(k, ("batch", None, None, None))
-    v = constrain_spec(v, ("batch", None, None, None))
     n_rep = cfg.n_heads // cfg.n_kv_heads
     hkv, hd = cfg.n_kv_heads, cfg.hd
     pos1d = jnp.arange(S)
@@ -333,7 +318,6 @@ def ref_attention_core(q, k, v, cfg, spec, block_k: int = 1024) -> jax.Array:
     # row index inside a kv head = r*S + s.
     q = q.reshape(B, S, hkv, n_rep, hd).transpose(0, 2, 3, 1, 4)
     q = q.reshape(B, hkv, n_rep * S, hd)
-    q = constrain_spec(q, ("batch", None, "model", None))
     k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)  # (B,Hkv,S,hd)
     o = flash_attention(
         q, k, v, jnp.tile(pos1d, n_rep), pos1d, True, spec.window,

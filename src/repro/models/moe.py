@@ -5,64 +5,18 @@ Token-choice top-k routing with a static per-expert capacity
 highest-priority assigned tokens (priority = router probability), computes
 a gated-MLP, and the results are scatter-combined with the routing
 weights. Dropped tokens (over capacity) fall back to the residual stream,
-the standard GShard/Switch behaviour.
+the standard GShard/Switch behaviour. Routing is global: every token of
+the (B, S) batch competes for the same E × C expert slots.
 
 FLOPs are ``E × C × expert_mlp`` ≈ ``top_k × T × expert_mlp ×
 capacity_factor`` — i.e. the *active* parameter count, which is what the
 roofline's ``6·N_active·D`` model expects.
-
-Sharding: experts are laid out on the ``model`` mesh axis when divisible
-(expert parallelism — dispatch/combine lower to all-to-alls under GSPMD);
-otherwise the per-expert FFN dim is tensor-parallel (grok: 8 experts on a
-16-way axis).
-
-Group-limited routing (§Perf-hillclimb kimi iter B): on a production
-mesh, tokens are split into ``G = pod×data`` groups aligned to the batch
-sharding and routed *independently* with per-group capacity ``C/G``.
-This keeps the dispatch gather local to each data shard (the global
-(T,E) route makes GSPMD all-reduce the (E,C,d) dispatched tensor over
-``data`` and replicate expert compute ×|data|), and is how
-DeepSeek/Kimi-family deployments dispatch in practice. ``n_groups=1``
-recovers the exact global-routing semantics (the CPU-test default).
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-
-from repro import compat
-from repro.core.psharding import ambient_mesh, constrain_spec, n_data_shards
-
-
-def _local_topk(x, k, axes):
-    """jax.lax.top_k with shard-local semantics on a production mesh.
-
-    XLA's TopK/Sort partitioner all-gathers the *batch* dims over `data`
-    (measured: 2×98 GB/layer on kimi×train_4k) even when the sort dim is
-    unsharded. Wrapping the op in shard_map keeps it local; ``axes`` is a
-    per-dim logical spec as in ``constrain_spec``, which must already be
-    the operand's sharding. Falls back to plain top_k without a mesh.
-    """
-    mesh = ambient_mesh()
-    if mesh is None or "model" not in mesh.axis_names:
-        return jax.lax.top_k(x, k)
-    dp = [a for a in ("pod", "data") if a in mesh.axis_names]
-    spec = []
-    for dim, ax in zip(x.shape, axes):
-        if ax == "batch" and dp and dim % n_data_shards(mesh) == 0:
-            spec.append(tuple(dp) if len(dp) > 1 else dp[0])
-        elif ax == "model" and dim % mesh.shape["model"] == 0:
-            spec.append("model")
-        else:
-            spec.append(None)
-    from jax.sharding import PartitionSpec as P
-
-    pspec = P(*spec)
-    return compat.shard_map(
-        lambda v: tuple(jax.lax.top_k(v, k)),
-        mesh=mesh, in_specs=pspec, out_specs=(pspec, pspec), check_rep=False,
-    )(x)
 
 
 def init_moe(rng, d: int, spec, dtype=jnp.float32):
@@ -83,100 +37,51 @@ def _capacity(T: int, spec, capacity_factor=None) -> int:
     return min(T, c)  # top_k needs k <= size along the token axis
 
 
-def _auto_groups(B: int, S: int, spec) -> int:
-    """Batch-aligned group count: pod×data shards when divisible, else 1.
-
-    Grouping only pays when each group has enough tokens to fill expert
-    capacity naturally; at decode (T=B tokens) the per-group capacity
-    floor (≥1, sublane-rounded) would inflate dispatched slots ~16×
-    (measured: kimi×decode_32k collective 0.05→5.2 s). Fall back to
-    global routing when K·Tg < 8·E.
-    """
-    g = n_data_shards()
-    if g <= 1 or B % g != 0:
-        return 1
-    if spec.top_k * (B // g) * S < 8 * spec.n_experts:
-        return 1
-    return g
-
-
 def moe_forward(p, x: jax.Array, spec, return_aux: bool = False,
-                capacity_factor=None, n_groups: int | None = None):
+                capacity_factor=None):
     """x: (B, S, d) -> (B, S, d) [+ aux losses dict]."""
     B, S, d = x.shape
     E, K = spec.n_experts, spec.top_k
-    G = _auto_groups(B, S, spec) if n_groups is None else n_groups
-    Tg = (B // G) * S
-    C = _capacity(Tg, spec, capacity_factor)
-    xg = constrain_spec(x.reshape(G, Tg, d), ("batch", None, None))
+    T = B * S
+    C = _capacity(T, spec, capacity_factor)
+    xt = x.reshape(T, d)
 
-    logits = (xg.astype(jnp.float32)) @ p["router"]  # (G, Tg, E)
-    logits = constrain_spec(logits, ("batch", None, None))
+    logits = (xt.astype(jnp.float32)) @ p["router"]  # (T, E)
     probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_e = _local_topk(probs, K, ("batch", None, None))  # (G, Tg, K)
-    top_p = constrain_spec(top_p, ("batch", None, None))
-    top_e = constrain_spec(top_e, ("batch", None, None))
+    top_p, top_e = jax.lax.top_k(probs, K)  # (T, K)
 
-    # assignment matrix with router-prob priorities: (G, E, Tg).
-    # vmap over G so the scatter keeps G as an operand-batching dim —
-    # fancy-indexing G turns it into a scatter dim and GSPMD then
-    # replicates the output over the mesh (§Perf-hillclimb kimi iter C).
-    def _assign(tp, te):  # (Tg,K) -> (Tg,E)
-        a = jnp.zeros((Tg, E), jnp.float32)
-        return a.at[jnp.arange(Tg)[:, None], te].set(tp)
+    # assignment matrix with router-prob priorities, zero where unassigned
+    assign = jnp.zeros((T, E), jnp.float32).at[jnp.arange(T)[:, None], top_e].set(top_p)
+    prio = assign.T  # (E, T)
 
-    assign = jax.vmap(_assign)(top_p, top_e)
-    prio = jnp.swapaxes(assign, 1, 2)  # (G, E, Tg), zero where unassigned
-    # slice E over `model` *before* the per-expert top-k so it runs local
-    # (iter D: otherwise GSPMD all-gathers the (G,Tg,E) route twice/layer)
-    prio = constrain_spec(prio, ("batch", "model", None))
-
-    # Expert-parallel: E over `model` when divisible (the divisibility
-    # guard makes this a no-op otherwise — grok instead gets d_ff
-    # tensor-parallel experts via the TP_ALT weight rule; a C-sharded
-    # dispatch variant was tried and refuted, see §Perf-hillclimbs).
-    exp3, exp4 = ("batch", "model", None), ("batch", "model", None, None)
-
-    # per-expert top-C tokens by priority, within each group
-    gate, idx = _local_topk(prio, C, exp3)  # (G, E, C)
-    gate = constrain_spec(gate, exp3)
-    idx = constrain_spec(idx, exp3)
+    # per-expert top-C tokens by priority
+    gate, idx = jax.lax.top_k(prio, C)  # (E, C)
     valid = gate > 0.0
 
-    # dispatch: group-local gather — vmap over G keeps the gather batched
-    # (no tokens move between data shards)
-    xe = jax.vmap(lambda xt, i: jnp.take(xt, i.reshape(-1), axis=0))(xg, idx)
-    xe = xe.reshape(G, E, C, d)
-    xe = constrain_spec(xe, exp4)
-    h = jax.nn.silu(jnp.einsum("gecd,edf->gecf", xe, p["wg"])) * jnp.einsum(
-        "gecd,edf->gecf", xe, p["wi"]
+    # dispatch: gather each expert's tokens
+    xe = jnp.take(xt, idx.reshape(-1), axis=0).reshape(E, C, d)
+    h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", xe, p["wg"])) * jnp.einsum(
+        "ecd,edf->ecf", xe, p["wi"]
     )
-    ye = jnp.einsum("gecf,efd->gecd", h, p["wo"])  # (G, E, C, d)
-    ye = constrain_spec(ye, exp4)
+    ye = jnp.einsum("ecf,efd->ecd", h, p["wo"])  # (E, C, d)
 
-    # combine: scatter-add gate-weighted expert outputs back to tokens,
-    # batched over G (partial over `model` -> one (Tg,d)-sized AR/group)
-    w = jnp.where(valid, gate, 0.0).astype(ye.dtype)  # (G, E, C)
-
-    def _combine(i, yw):  # (E,C), (E,C,d) -> (Tg,d)
-        o = jnp.zeros((Tg, d), yw.dtype)
-        return o.at[i.reshape(-1)].add(yw.reshape(E * C, d))
-
-    out = jax.vmap(_combine)(idx, ye * w[..., None])
-    out = constrain_spec(out, ("batch", None, None))
+    # combine: scatter-add gate-weighted expert outputs back to tokens
+    w = jnp.where(valid, gate, 0.0).astype(ye.dtype)  # (E, C)
+    out = jnp.zeros((T, d), ye.dtype).at[idx.reshape(-1)].add(
+        (ye * w[..., None]).reshape(E * C, d))
     out = out.reshape(B, S, d).astype(x.dtype)
 
     if not return_aux:
         return out
-    # Switch-style load-balance loss (means over all groups/tokens)
-    me = jnp.mean(probs, axis=(0, 1))  # (E,)
+    # Switch-style load-balance loss (means over all tokens)
+    me = jnp.mean(probs, axis=0)  # (E,)
     fe = jnp.mean(
-        jnp.sum(jax.nn.one_hot(top_e, E), axis=2), axis=(0, 1)
+        jnp.sum(jax.nn.one_hot(top_e, E), axis=1), axis=0
     )  # fraction of tokens to each expert
     aux = {
         "load_balance": E * jnp.sum(me * fe),
         "router_z": jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1))),
-        "dropped_frac": 1.0 - jnp.sum(valid) / (G * Tg * K),
+        "dropped_frac": 1.0 - jnp.sum(valid) / (T * K),
     }
     return out, aux
 

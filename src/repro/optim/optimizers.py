@@ -1,8 +1,9 @@
 """Optimizers from scratch (no optax in this environment).
 
 AdamW and SGD+momentum over arbitrary pytrees, with global-norm clipping
-and the usual schedules. State layouts are plain pytrees so they shard
-with the same rules as their parameters (FSDP-friendly).
+and the usual schedules. State layouts are plain pytrees shaped like
+their parameters, so they take the same shardings (replicated, on the
+edge mesh).
 """
 
 from __future__ import annotations

@@ -15,7 +15,7 @@ instead of a CLI script. An :class:`EdgeSession` takes a validated
   the micro count; a path replays a saved plan; ``None`` pins the mesh
   to dp×stages and keeps the planner as an offline report), derives the
   executable :class:`~repro.core.planner.StagePartition`, and builds
-  the mesh via :mod:`repro.launch.mesh`.
+  the mesh via :mod:`repro.core.mesh`.
 * **cache** — opens the (optionally persistent) activation cache with
   the shared :func:`~repro.core.activation_cache.manifest_for` identity
   and runs each fully-resident epoch through a
@@ -139,17 +139,14 @@ class EdgeSession:
 
     def _build_plan(self, pool: int, planner_mb: int, n_micro: int, max_stages):
         """One construction site for both the executed plan and the
-        offline report: period-granular costs (analytic or
-        HLO-calibrated) through Alg. 1."""
-        from repro.core.planner import HybridParallelismPlanner, JETSON_NANO_H
-        from repro.launch.costs import resolve_cost_model
+        offline report: analytic period-granular costs through Alg. 1."""
+        from repro.core.planner import (
+            HybridParallelismPlanner, JETSON_NANO_H, period_costs)
 
         spec = self.spec
-        cost_model = resolve_cost_model(
-            spec.calibrate, micro_batch=max(1, spec.batch // n_micro),
-            quant_bits=spec.quant)
         return HybridParallelismPlanner(
-            cost_model.period_costs(self.cfg, "pac", seq_len=spec.seq),
+            period_costs(self.cfg, "pac", seq_len=spec.seq,
+                         quant_bits=spec.quant),
             [JETSON_NANO_H] * pool, planner_mb, n_micro,
         ).plan(max_stages=max_stages)
 
@@ -171,7 +168,7 @@ class EdgeSession:
         from repro.core.parallel_adapters import init_adapter
         from repro.core.quantization import quantize_tree, tree_storage_bytes
         from repro.data import DataPipeline, SyntheticPersonalCorpus
-        from repro.launch.mesh import make_edge_mesh, make_plan_mesh
+        from repro.core.mesh import make_edge_mesh, make_plan_mesh
         from repro.models import backbone as bb
         from repro.optim import adamw_init
 
@@ -207,9 +204,6 @@ class EdgeSession:
                     plan = self._build_plan(pool, spec.batch // n_micro,
                                             n_micro, smax)
             else:
-                if spec.calibrate:
-                    log("note: --calibrate has no effect when replaying a "
-                        "saved plan; re-run with --plan auto to replan")
                 plan = self._saved_plan
             mb = spec.batch // n_micro
             partition = plan.stage_partition()
@@ -413,7 +407,7 @@ class EdgeSession:
         batch's concrete tree structure."""
         import jax
 
-        from repro.launch import sharding as shard
+        from repro.core.mesh import cached_batch_axes, cached_step_shardings
 
         spec, steps = self.spec, self._steps_mod
         if self._use_pallas:
@@ -424,12 +418,12 @@ class EdgeSession:
                     steps.dp_cached_train_step, cfg=self.cfg,
                     mesh=self.mesh, r=spec.r, lr=spec.lr,
                     kernel_impl="pallas",
-                    batch_axes=shard.cached_batch_axes(cached, self.mesh)),
+                    batch_axes=cached_batch_axes(cached, self.mesh)),
                 donate_argnums=(1, 2))
         return jax.jit(
             functools.partial(steps.pac_cached_train_step, cfg=self.cfg,
                               r=spec.r, lr=spec.lr),
-            in_shardings=shard.cached_step_shardings(
+            in_shardings=cached_step_shardings(
                 self.backbone, self.adapter, self.opt, cached, self.mesh),
             donate_argnums=(1, 2))
 
@@ -586,7 +580,7 @@ class EdgeSession:
             raise RunSpecError(
                 "reshard() applies to multi-device sessions; single-device "
                 "jobs reshard via repro.fleet.ElasticDpRunner")
-        from repro.launch.mesh import make_edge_mesh
+        from repro.core.mesh import make_edge_mesh
 
         dp = int(dp)
         if dp < 1:

@@ -1,7 +1,7 @@
 """RunSpec — the typed, serializable description of one fine-tuning run.
 
-The trainer CLI's flag soup, the examples' hand-rolled constant blocks,
-and the benchmarks' ad-hoc wiring all collapse into this one dataclass:
+The trainer CLI's flags, the examples' constant blocks and the on-chip
+benchmark's wiring all collapse into this one dataclass:
 a :class:`RunSpec` is the single source of truth an
 :class:`~repro.runtime.session.EdgeSession` executes. It is pure Python
 (safe to build, validate, and JSON-round-trip before any JAX backend
@@ -65,7 +65,6 @@ class RunSpec:
     plan: Optional[str] = None
     pool: Optional[int] = None
     save_plan: Optional[str] = None
-    calibrate: bool = False
     # compute path for BOTH the epoch-1 frozen forward (OpSet dispatch:
     # quantized matmuls, Pallas flash attention, storage-form taps) and
     # the cached-epoch step ("ref" = dense jnp oracle)
@@ -212,6 +211,6 @@ class RunSpec:
             cache_compress=ns.cache_compress,
             cache_budget_mb=ns.cache_budget_mb, dp=ns.dp, stages=ns.stages,
             micro=ns.micro, plan=ns.plan, pool=ns.pool,
-            save_plan=ns.save_plan, calibrate=ns.calibrate,
+            save_plan=ns.save_plan,
             kernels=ns.kernels, ckpt=ns.ckpt,
         )

@@ -107,8 +107,9 @@ def test_softcap_bounds():
 
 
 def test_grouped_head_gqa_equals_repeated_kv():
-    """§Perf kimi iter G: folding the n_rep q-heads sharing a KV head into
-    the query-row axis must equal explicit KV repetition."""
+    """Grouped-head GQA (``ref_attention_core``): folding the n_rep q-heads
+    sharing a KV head into the query-row axis must equal explicit KV
+    repetition."""
     B, Hkv, n_rep, S, hd = 2, 2, 4, 32, 8
     H = Hkv * n_rep
     ks = jax.random.split(jax.random.PRNGKey(7), 3)

@@ -272,7 +272,7 @@ _PIPELINE_PARITY = textwrap.dedent(
     from repro.core import steps
     from repro.core.parallel_adapters import init_adapter
     from repro.core.quantization import quantize_tree
-    from repro.launch.mesh import make_edge_mesh
+    from repro.core.mesh import make_edge_mesh
     from repro.models import backbone as bb
 
     cfg = get_arch("internlm2-1.8b").reduced()

@@ -14,6 +14,8 @@ import os
 import subprocess
 import sys
 
+import pytest
+
 REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
@@ -80,6 +82,27 @@ def test_layering_examples_must_not_touch_trainer_privates(tmp_path):
     })
     assert rules_fired(result) == {"layering"}
     assert len(result.findings) == 2
+
+
+_LAUNCH_IMPORTS = (
+    "from repro.launch.train import main\n"
+    "import repro.launch.fleet\n"
+    "from repro import launch\n"
+    "from ..launch import train\n"
+    "from repro.launcher import x\n"  # another package: never flagged
+)
+
+
+@pytest.mark.parametrize("rel, n_flagged", [
+    ("src/repro/core/steps.py", 4),
+    ("src/repro/launch/train.py", 0),
+])
+def test_layering_only_launch_imports_launch(tmp_path, rel, n_flagged):
+    """launch/ holds the entry points only: the library below it may not
+    import repro.launch, in any spelling; launch/ itself may."""
+    result = lint_tree(tmp_path, {rel: _LAUNCH_IMPORTS})
+    assert len(result.findings) == n_flagged, [f.render() for f in result.findings]
+    assert rules_fired(result) <= {"layering"}
 
 
 def test_layering_core_may_import_kernels(tmp_path):
@@ -356,53 +379,6 @@ def test_storage_form_sanctions_paging_but_not_the_engine(tmp_path):
     assert ok.ok, [f.render() for f in ok.findings]
     bad = lint_tree(tmp_path, {"src/repro/serve/engine.py": widen})
     assert rules_fired(bad) == {"storage-form"}
-
-
-# ---------------------------------------------------------- bench-schema
-
-
-GOOD_BENCH = {
-    "arch": "gemma2-2b", "backend": "cpu", "pallas_interpret_mode": True,
-    "batch": 8, "seq": 128, "steps": 4, "step_ms": 12.5,
-}
-
-
-def test_bench_schema_accepts_valid_record(tmp_path):
-    result = lint_tree(tmp_path, {
-        "BENCH_good.json": json.dumps(GOOD_BENCH),
-    })
-    assert result.ok, [f.render() for f in result.findings]
-
-
-def test_bench_schema_flags_missing_and_mistyped_keys(tmp_path):
-    bad = dict(GOOD_BENCH)
-    del bad["pallas_interpret_mode"]   # required key missing
-    bad["step_ms"] = "12.5"            # numeric field as string
-    result = lint_tree(tmp_path, {"BENCH_bad.json": json.dumps(bad)})
-    assert rules_fired(result) == {"bench-schema"}
-    assert len(result.findings) == 2
-
-
-def test_bench_schema_per_file_required_keys(tmp_path):
-    """The serving bench additionally needs page geometry and the
-    per-policy breakdown; rate fields must be numeric."""
-    good = dict(GOOD_BENCH, page_size=8, policies={
-        "int8": {"kv_bytes_per_token": 544, "ref_tokens_per_s": 100.0},
-    })
-    ok = lint_tree(tmp_path / "good", {
-        "BENCH_decode_step.json": json.dumps(good),
-        # the per-file keys do not leak onto other records
-        "BENCH_other.json": json.dumps(GOOD_BENCH),
-    })
-    assert ok.ok, [f.render() for f in ok.findings]
-
-    bad = dict(GOOD_BENCH, policies={
-        "int8": {"kv_bytes_per_token": "544", "ref_tokens_per_s": "fast"},
-    })  # page_size missing, both rate/footprint fields stringly typed
-    result = lint_tree(tmp_path / "bad",
-                       {"BENCH_decode_step.json": json.dumps(bad)})
-    assert rules_fired(result) == {"bench-schema"}
-    assert len(result.findings) == 3
 
 
 # ----------------------------------------------------------- suppression

@@ -127,7 +127,7 @@ _SUBPROCESS_PIPELINE = textwrap.dedent(
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import jax, jax.numpy as jnp
     from repro.core.pipeline import stack_stages, pipeline_apply
-    from repro.launch.mesh import make_mesh
+    from repro.compat import make_mesh
 
     mesh = make_mesh((4,), ("stage",))
     n_p, d = 8, 16
@@ -168,7 +168,7 @@ _SUBPROCESS_PIPELINE_TAPS = textwrap.dedent(
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import jax, jax.numpy as jnp
     from repro.core.pipeline import stack_stages, pipeline_apply
-    from repro.launch.mesh import make_mesh
+    from repro.compat import make_mesh
 
     # 2-D (dp, stage) mesh: dim 1 of x_micro sharded over dp
     mesh = make_mesh((2, 2), ("dp", "stage"))
@@ -215,58 +215,3 @@ def test_spmd_pipeline_collects_all_stage_taps_on_dp_mesh():
     """Every stage's per-period activations assemble into layer-ordered
     taps (what PAC+ caches), with the micro-batch dim sharded over dp."""
     assert "PIPELINE_TAPS_OK" in _run_sub(_SUBPROCESS_PIPELINE_TAPS)
-
-
-_SUBPROCESS_DP = textwrap.dedent(
-    """
-    import os
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    import functools
-    import jax, jax.numpy as jnp
-    from jax.sharding import PartitionSpec as P, NamedSharding
-    from repro.configs import get_arch
-    from repro.core import steps
-    from repro.core.parallel_adapters import init_adapter
-    from repro.models import backbone as bb
-    from repro.optim import adamw_init
-    from repro.launch.mesh import make_mesh
-    from repro.launch import sharding as shard
-
-    cfg = get_arch("internlm2-1.8b").reduced()
-    mesh = make_mesh((4, 2), ("data", "model"))
-    bp = bb.init_backbone(jax.random.PRNGKey(0), cfg)
-    ap = init_adapter(jax.random.PRNGKey(1), cfg, r=4)
-    opt = adamw_init(ap)
-    B, S = 8, 16
-    batch = {
-        "tokens": jax.random.randint(jax.random.PRNGKey(2), (B, S), 0, cfg.vocab),
-        "labels": jax.random.randint(jax.random.PRNGKey(3), (B, S), 0, cfg.vocab),
-    }
-    fn = functools.partial(steps.pac_train_step, cfg=cfg, r=4)
-    # single device reference
-    loss_ref, ap_ref, _, _ = fn(bp, ap, opt, batch)
-    # sharded execution on the 4x2 mesh
-    p_sh = shard.to_named(shard.param_specs(bp, mesh), mesh)
-    a_sh = shard.to_named(shard.param_specs(ap, mesh), mesh)
-    o_sh = shard.to_named(shard.param_specs(opt, mesh), mesh)
-    b_sh = shard.to_named(shard.batch_specs(batch, mesh), mesh)
-    with mesh:
-        jf = jax.jit(fn, in_shardings=(p_sh, a_sh, o_sh, b_sh))
-        loss_sh, ap_sh, _, _ = jf(bp, ap, opt, batch)
-    assert abs(float(loss_ref) - float(loss_sh)) < 1e-4, (float(loss_ref), float(loss_sh))
-    d = max(
-        float(jnp.max(jnp.abs(a - b)))
-        for a, b in zip(jax.tree.leaves(ap_ref), jax.tree.leaves(ap_sh))
-    )
-    # f32 reduction order differs across shards; AdamW's m/(sqrt(v)+eps)
-    # amplifies that near zero-gradient elements, so the post-update bound
-    # is looser than the loss bound (real sharding bugs are O(1) off)
-    assert d < 1e-3, d
-    print("SPMD_STEP_OK")
-    """
-)
-
-
-def test_sharded_pac_step_matches_single_device():
-    """The production sharding rules preserve numerics on a real 4×2 mesh."""
-    assert "SPMD_STEP_OK" in _run_sub(_SUBPROCESS_DP)

@@ -37,7 +37,7 @@ from repro.core.planner import (
     model_layer_costs,
     period_costs,
 )
-from repro.configs import get_arch
+from repro.configs import get_arch, list_archs
 
 
 def _run_sub(code: str) -> str:
@@ -104,43 +104,20 @@ def test_layer_granularity_plan_refuses_off_period_cut():
         plan.stage_partition(layers_per_period=lpp)
 
 
-def test_aggregate_periods_sums_flops_keeps_boundary_act():
-    cfg = get_arch("t5-base-pac")
+@pytest.mark.parametrize("arch", list_archs())
+def test_aggregate_periods_sums_flops_keeps_boundary_act(arch):
+    cfg = get_arch(arch)
     layer = model_layer_costs(cfg, "pac", seq_len=64)
     per = aggregate_periods(layer, cfg.period)
     assert len(per) == cfg.n_periods
-    assert per[0].fwd_flops == pytest.approx(
-        sum(c.fwd_flops for c in layer[: cfg.period]))
-    # inter-stage comm is the boundary activation, not the sum
-    assert per[0].act_bytes == layer[cfg.period - 1].act_bytes
+    P = cfg.period
+    for i, c in enumerate(per):
+        assert c.fwd_flops == pytest.approx(
+            sum(l.fwd_flops for l in layer[i * P:(i + 1) * P]))
+        # inter-stage comm is the boundary activation, not the sum
+        assert c.act_bytes == layer[(i + 1) * P - 1].act_bytes
     with pytest.raises(ValueError):
         aggregate_periods(layer, len(layer) + 1)
-
-
-def test_hlo_calibrated_cost_model():
-    """The calibrated backend keeps analytic memory accounting, prices the
-    backbone forward close to the analytic model (they should agree — both
-    count the same matmuls), and captures the head/CE/optimizer overhead
-    the closed form omits on the trainable side."""
-    from repro.launch.costs import AnalyticCostModel, CostModel, HloCalibratedCostModel
-
-    cfg = get_arch("internlm2-1.8b").reduced()
-    ana = AnalyticCostModel()
-    cal = HloCalibratedCostModel(micro_batch=2)
-    assert isinstance(ana, CostModel) and isinstance(cal, CostModel)
-    base = ana.period_costs(cfg, "pac", seq_len=16)
-    pc = cal.period_costs(cfg, "pac", seq_len=16)
-    assert len(pc) == cfg.n_periods == len(base)
-    for b, c in zip(base, pc):
-        assert c.param_bytes == b.param_bytes  # memory stays analytic
-        assert c.resident_act_bytes == b.resident_act_bytes
-        # measured backbone fwd within 25% of the analytic count
-        assert c.fwd_flops == pytest.approx(b.fwd_flops, rel=0.25)
-        # the trainable side includes head/CE/optimizer the analytic omits
-        assert c.bwd_flops > b.bwd_flops
-    # calibration targets the PAC+ path; other techniques pass through
-    assert cal.period_costs(cfg, "full", seq_len=16) == ana.period_costs(
-        cfg, "full", seq_len=16)
 
 
 # ---------------------------------------------------------------------------
@@ -183,11 +160,11 @@ _UNIFORM_BITWISE = textwrap.dedent(
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import jax, jax.numpy as jnp
     import numpy as np
-    from repro.configs import get_arch
+    from repro.configs import get_arch, list_archs
     from repro.core import steps
     from repro.core.parallel_adapters import init_adapter
     from repro.core.planner import StagePartition
-    from repro.launch.mesh import make_edge_mesh
+    from repro.core.mesh import make_edge_mesh
     from repro.models import backbone as bb
 
     cfg = get_arch("internlm2-1.8b").reduced()   # 2 periods
@@ -228,14 +205,14 @@ _RAGGED_EQUIV = textwrap.dedent(
     import dataclasses, functools
     import jax, jax.numpy as jnp
     import numpy as np
-    from repro.configs import get_arch
+    from repro.configs import get_arch, list_archs
     from repro.core import steps
     from repro.core.activation_cache import ActivationCache
     from repro.core.parallel_adapters import init_adapter
     from repro.core.planner import (
         HybridParallelismPlanner, JETSON_NANO_H, JETSON_NANO_L, JETSON_TX2_H,
         period_costs)
-    from repro.launch.mesh import make_plan_mesh
+    from repro.core.mesh import make_plan_mesh
     from repro.models import backbone as bb
     from repro.optim import adamw_init
 

@@ -76,7 +76,7 @@ def test_runspec_from_args_inverts_no_cache():
         batch=4, seq=16, seed=1, r=8, init="pruning", quant=None, lr=3e-3,
         no_cache=True, cache_dir=None, cache_compress="f32",
         cache_budget_mb=64, dp=1, stages=1, micro=None, plan=None,
-        pool=None, save_plan=None, calibrate=False, kernels="ref", ckpt=None)
+        pool=None, save_plan=None, kernels="ref", ckpt=None)
     spec = RunSpec.from_args(ns)
     assert spec.use_cache is False and spec.seed == 1 and spec.reduced
     ns.no_cache = False
@@ -269,8 +269,8 @@ _GOLDEN_DP = textwrap.dedent(
     from repro.core.activation_cache import ActivationCache
     from repro.core.init_methods import pruning_init
     from repro.data import DataPipeline, SyntheticPersonalCorpus
-    from repro.launch import sharding as shard
-    from repro.launch.mesh import make_edge_mesh
+    from repro.core import mesh as shard
+    from repro.core.mesh import make_edge_mesh
     from repro.models import backbone as bb
     from repro.optim import adamw_init
 

@@ -1,83 +1,113 @@
-"""Sharding rules, data pipeline, checkpoint, optimizer units."""
+"""Edge-mesh shardings, data pipeline, checkpoint, optimizer units."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import load_checkpoint, save_checkpoint
-from repro.configs import INPUT_SHAPES, get_arch
+from repro.compat import abstract_mesh
+from repro.configs import get_arch, list_archs
+from repro.core import mesh as mesh_mod
+from repro.core.activation_cache import COMPRESS_POLICIES, ActivationCache
+from repro.core.parallel_adapters import init_adapter
 from repro.core.quantization import QTensor, quantize_tree
 from repro.data import DataPipeline, SyntheticPersonalCorpus, glue_like_task
-from repro.launch import sharding as shard
-from repro.launch.mesh import make_mesh
-from repro.launch.specs import abstract_params, input_specs, resolve_cfg_for_shape
 from repro.models import backbone as bb
 from repro.optim import adamw_init, adamw_update, clip_by_global_norm, cosine_schedule
 
 
-def _mesh11():
-    return make_mesh((1, 1), ("data", "model"))
+def _leaves(tree):
+    return jax.tree.leaves(tree, is_leaf=lambda x: isinstance(x, QTensor))
 
 
-ASSIGNED = [
-    "musicgen-large", "grok-1-314b", "moonshot-v1-16b-a3b", "kimi-k2-1t-a32b",
-    "qwen2-vl-7b", "xlstm-125m", "gemma2-2b", "jamba-1.5-large-398b",
-    "internlm2-1.8b", "granite-20b",
-]
+def _batch_entry(axes):
+    return tuple(axes) if len(axes) > 1 else axes[0]
 
 
-@pytest.mark.parametrize("arch", ASSIGNED)
-def test_param_specs_cover_all_leaves(arch):
-    cfg = get_arch(arch)
-    mesh = _mesh11()
-    params = abstract_params(cfg)
-    specs = shard.param_specs(params, mesh)
-    n_p = len(jax.tree.leaves(params, is_leaf=lambda x: isinstance(x, QTensor)))
-    n_s = len(jax.tree.leaves(specs, is_leaf=lambda x: isinstance(x, (P, QTensor))))
-    assert n_p == n_s
-    for leaf, spec in zip(
-        jax.tree.leaves(params, is_leaf=lambda x: isinstance(x, QTensor)),
-        jax.tree.leaves(specs, is_leaf=lambda x: isinstance(x, (P, QTensor))),
-    ):
-        if isinstance(leaf, QTensor):
-            assert isinstance(spec, QTensor)
-        else:
-            assert len(spec) <= leaf.ndim
+@pytest.mark.parametrize("arch", list_archs())
+def test_edge_shardings_cover_every_leaf(arch):
+    """``cached_step_shardings`` on a (dp 2, stage 2) mesh: every leaf of
+    an INT8 backbone (each QTensor's payload and scales), the adapter,
+    its AdamW state and a cached batch gets a sharding; parameters are
+    replicated; every cached leaf's batch dim lies on the cached axes."""
+    cfg = get_arch(arch).reduced()
+    key = jax.random.PRNGKey(0)
+    bp = jax.eval_shape(lambda: quantize_tree(bb.init_backbone(key, cfg), bits=8))
+    ap = jax.eval_shape(lambda: init_adapter(key, cfg, r=4))
+    opt = jax.eval_shape(adamw_init, ap)
+    B, S, d, n_p = 8, 16, cfg.d_model, cfg.n_periods
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
+    cached = {"b0": f32(B, S, d), "b_final": f32(B, S, d),
+              "taps": {"q": jax.ShapeDtypeStruct((n_p, B, S, d), jnp.int8),
+                       "scale": f32(n_p, B, S, 1)},
+              "labels": jax.ShapeDtypeStruct((B, S), jnp.int32)}
+    mesh = abstract_mesh((2, 2), ("dp", "stage"))
+    shardings = mesh_mod.cached_step_shardings(bp, ap, opt, cached, mesh)
+
+    qtensors = [l for l in _leaves(bp) if isinstance(l, QTensor)]
+    assert qtensors, "the INT8 backbone must hold quantized leaves"
+    for tree, sh in zip((bp, ap, opt), shardings[:3]):
+        assert jax.tree.structure(tree) == jax.tree.structure(sh)
+        for spec in jax.tree.leaves(sh):
+            assert isinstance(spec, NamedSharding) and spec.spec == P()
+    # each QTensor's payload and scales are two leaves, each sharded
+    assert len(jax.tree.leaves(shardings[0])) == len(_leaves(bp)) + len(qtensors)
+    for q in jax.tree.leaves(shardings[0], is_leaf=lambda x: isinstance(x, QTensor)):
+        if isinstance(q, QTensor):
+            assert isinstance(q.q, NamedSharding) and isinstance(q.scale, NamedSharding)
+    axes = mesh_mod.cached_batch_axes(cached, mesh)
+    assert axes == ("dp", "stage")
+    flat, _ = jax.tree_util.tree_flatten_with_path(shardings[3])
+    assert len(flat) == len(jax.tree.leaves(cached)) == 5
+    for path, spec in flat:
+        bdim = 1 if mesh_mod.path_names(path)[0] == "taps" else 0
+        assert spec.spec[bdim] == _batch_entry(axes), (path, spec.spec)
+        assert all(e is None for i, e in enumerate(spec.spec) if i != bdim)
 
 
-@pytest.mark.parametrize("arch", ["kimi-k2-1t-a32b", "granite-20b"])
-def test_quantized_param_specs(arch):
-    cfg = get_arch(arch)
-    mesh = _mesh11()
-    params = abstract_params(cfg, quant_bits=8)
-    specs = shard.param_specs(params, mesh)
-    qleaves = [
-        l for l in jax.tree.leaves(specs, is_leaf=lambda x: isinstance(x, QTensor))
-        if isinstance(l, QTensor)
-    ]
-    assert qleaves, "quantized params must produce QTensor specs"
+# (dp, stage, batch) -> the axes the cached batch must shard over
+_LAYOUTS = {
+    "dp2-stage1": (2, 1, 8, ("dp", "stage")),
+    "dp1-stage2": (1, 2, 8, ("dp", "stage")),
+    "dp2-stage2-b8": (2, 2, 8, ("dp", "stage")),
+    "dp2-stage2-b6": (2, 2, 6, ("dp",)),
+}
 
 
-@pytest.mark.parametrize("shape_name", list(INPUT_SHAPES))
-@pytest.mark.parametrize("arch", ["internlm2-1.8b", "qwen2-vl-7b", "musicgen-large"])
-def test_input_specs_shapes(arch, shape_name):
-    cfg = get_arch(arch)
-    shape = INPUT_SHAPES[shape_name]
-    cfg2, note = resolve_cfg_for_shape(cfg, shape)
-    batch = input_specs(cfg2, shape)
-    B = shape.global_batch
-    if cfg.frontend:
-        assert batch["embeds"].shape[0] == B
-        assert batch["embeds"].shape[2] == cfg.d_model
-    else:
-        assert batch["tokens"].shape[0] == B
-    if shape.mode == "decode":
-        lead = batch.get("tokens", batch.get("embeds"))
-        assert lead.shape[1] == 1  # ONE new token
-    if shape.name == "long_500k" and not cfg.is_subquadratic():
-        assert note == "sw8k" and cfg2.is_subquadratic()
+@pytest.mark.parametrize("layout", list(_LAYOUTS))
+@pytest.mark.parametrize("policy", COMPRESS_POLICIES)
+def test_cached_batch_specs(policy, layout):
+    """The cached batch as ``ActivationCache.get_batch`` hands it back
+    (int8 in storage form) shards its batch dim over the data axes, with
+    ``stage`` joining only where the batch divides the whole pool."""
+    dp, stages, B, want = _LAYOUTS[layout]
+    n_p, S, d = 2, 4, 128
+    rng = np.random.default_rng(0)
+    cache = ActivationCache(budget_bytes=1 << 24, compress=policy)
+    ids = np.arange(B)
+    cache.put_batch(ids, rng.standard_normal((B, S, d), np.float32),
+                    rng.standard_normal((n_p, B, S, d), np.float32),
+                    rng.standard_normal((B, S, d), np.float32))
+    b0, taps, bf = cache.get_batch(ids, with_final=True, dtype=None,
+                                   compressed=policy == "int8")
+    cached = {"b0": b0, "taps": taps, "b_final": bf,
+              "labels": np.zeros((B, S), np.int32)}
+    assert isinstance(taps, dict) == (policy == "int8")
+    mesh = abstract_mesh((dp, stages), ("dp", "stage"))
+    axes = mesh_mod.cached_batch_axes(cached, mesh)
+    assert axes == want
+    specs = mesh_mod.batch_specs(cached, axes)
+    flat, _ = jax.tree_util.tree_flatten_with_path(
+        specs, is_leaf=lambda x: isinstance(x, P))
+    leaves = jax.tree.leaves(cached)
+    assert len(flat) == len(leaves)
+    for (path, spec), leaf in zip(flat, leaves):
+        bdim = 1 if mesh_mod.path_names(path)[0] == "taps" else 0
+        assert leaf.shape[bdim] == B
+        assert spec == P(*[_batch_entry(want) if i == bdim else None
+                           for i in range(leaf.ndim)]), (path, spec)
 
 
 def test_corpus_learnable_structure_and_determinism():
@@ -149,48 +179,3 @@ def test_clip_and_schedule():
     assert float(jnp.sqrt(jnp.sum(jnp.square(g["a"])))) <= 1.0 + 1e-5
     lrs = [float(cosine_schedule(s, 100, 1.0, warmup_steps=10)) for s in range(100)]
     assert lrs[0] < lrs[9] and lrs[20] > lrs[90]
-
-
-# ---------------------------------------------------------------------------
-# psharding rule-table units (TP_ALT fallback, stacked-vs-slice lookup)
-# ---------------------------------------------------------------------------
-
-
-def test_resolve_tp_alt_fallback_fires_only_when_tp_fails():
-    from repro.compat import abstract_mesh
-    from repro.core.psharding import FSDP, TP, TP_ALT, resolve
-
-    mesh = abstract_mesh((2, 2), ("data", "model"))
-    # E=8 divides model=2 -> TP wins, TP_ALT stays None
-    spec = resolve((None, TP, FSDP, TP_ALT), (4, 8, 16, 32), mesh)
-    assert spec == P(None, "model", "data", None)
-    # E=3 does not divide -> TP_ALT takes the model axis (grok case)
-    spec = resolve((None, TP, FSDP, TP_ALT), (4, 3, 16, 32), mesh)
-    assert spec == P(None, None, "data", "model")
-    # neither divides -> nothing gets model
-    spec = resolve((None, TP, FSDP, TP_ALT), (4, 3, 16, 33), mesh)
-    assert spec == P(None, None, "data", None)
-
-
-def test_constrain_spec_is_noop_without_mesh():
-    from repro.core.psharding import constrain_spec
-
-    x = jnp.ones((4, 8, 16))
-    y = constrain_spec(x, ("batch", "model", None))
-    np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
-
-
-def test_slice_lookup_uses_stacked_rules():
-    """The scan slice of stacked MoE weights (E,d,f) must keep E on the
-    model axis (hillclimb kimi iter A): the rule lookup for a sliced leaf
-    goes through the stacked (ndim+1) table minus the scan dim."""
-    from repro.core.psharding import TP, TP_ALT, logical_for_param
-
-    # sliced MoE expert weight (E, d, f): stacked rule is (None, TP, FSDP, TP_ALT)
-    logical = logical_for_param(["blocks", "ffn", "wi"], 3 + 1)[1:]
-    kept = tuple(ax if ax in (TP, TP_ALT) else None for ax in logical)
-    assert kept == (TP, None, TP_ALT)
-    # sliced attention weight (d, H*hd): stacked rule (None, FSDP, TP)
-    logical = logical_for_param(["blocks", "mixer", "wq"], 2 + 1)[1:]
-    kept = tuple(ax if ax in (TP, TP_ALT) else None for ax in logical)
-    assert kept == (None, TP)

@@ -44,8 +44,8 @@ _EQUIVALENCE = textwrap.dedent(
     from repro.configs import get_arch
     from repro.core import steps
     from repro.core.parallel_adapters import init_adapter
-    from repro.launch import sharding as shard
-    from repro.launch.mesh import make_edge_mesh
+    from repro.core import mesh as shard
+    from repro.core.mesh import make_edge_mesh
     from repro.models import backbone as bb
     from repro.optim import adamw_init
 
@@ -134,7 +134,7 @@ _LAYOUT_ERRORS = textwrap.dedent(
     from repro.configs import get_arch
     from repro.core import steps
     from repro.core.parallel_adapters import init_adapter
-    from repro.launch.mesh import make_edge_mesh
+    from repro.core.mesh import make_edge_mesh
     from repro.models import backbone as bb
 
     cfg = get_arch("internlm2-1.8b").reduced()   # 2 periods
@@ -152,7 +152,7 @@ _LAYOUT_ERRORS = textwrap.dedent(
         assert "divisible" in str(e), e
     mesh3 = None
     try:  # 2 periods cannot split into 4 stages (and 4x1 has too few periods)
-        from repro.launch.mesh import make_edge_mesh as mk
+        from repro.core.mesh import make_edge_mesh as mk
         mesh4 = mk(1, 4)
         steps.pipeline_pac_loss_and_grads(
             bp, ap, {k: v[:4] for k, v in batch.items()},

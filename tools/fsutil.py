@@ -7,7 +7,6 @@ the two gates can never disagree about what they cover.
 
 from __future__ import annotations
 
-import fnmatch
 import os
 from typing import Callable, Iterable, Optional
 
@@ -28,7 +27,6 @@ def walk_files(
     *,
     root: Optional[str] = None,
     suffixes: Optional[tuple] = None,
-    patterns: Optional[tuple] = None,
     keep: Optional[Callable[[str], bool]] = None,
 ) -> list:
     """Expand files/directories into a sorted, deduplicated file list.
@@ -36,28 +34,21 @@ def walk_files(
     ``paths`` entries are taken relative to ``root`` (default:
     :func:`repo_root`) unless absolute; directories are walked
     recursively with :data:`SKIP_DIRS` pruned. A file is kept when it
-    matches any of ``suffixes`` (endswith) or ``patterns``
-    (fnmatch on the basename) — or unconditionally when neither filter
-    is given — and, if supplied, ``keep(path)`` returns True.
+    ends with one of ``suffixes`` (or unconditionally without them) and,
+    if supplied, ``keep(path)`` returns True.
     """
     base = root or repo_root()
 
     def _wanted(path: str) -> bool:
-        name = os.path.basename(path)
-        if suffixes or patterns:
-            ok = bool(suffixes and name.endswith(tuple(suffixes)))
-            ok = ok or bool(
-                patterns and any(fnmatch.fnmatch(name, p) for p in patterns)
-            )
-            if not ok:
-                return False
+        if suffixes and not os.path.basename(path).endswith(tuple(suffixes)):
+            return False
         return keep(path) if keep else True
 
     out = []
     for p in paths:
         full = p if os.path.isabs(p) else os.path.join(base, p)
         if os.path.isfile(full):
-            # explicitly named files bypass the suffix/pattern filter:
+            # explicitly named files bypass the suffix filter:
             # the caller asked for exactly this one
             if keep is None or keep(full):
                 out.append(os.path.abspath(full))

@@ -2,7 +2,7 @@
 
 Usage::
 
-    python -m tools.palint                      # default paths + BENCH_*.json
+    python -m tools.palint                      # default paths
     python -m tools.palint src tests            # explicit targets
     python -m tools.palint --json               # machine-readable output
     python -m tools.palint --list-rules         # rule catalog
@@ -27,8 +27,7 @@ def build_parser() -> argparse.ArgumentParser:
     )
     ap.add_argument(
         "paths", nargs="*",
-        help=f"files/directories to scan (default: {' '.join(DEFAULT_PATHS)} "
-             "+ repo-root BENCH_*.json)",
+        help=f"files/directories to scan (default: {' '.join(DEFAULT_PATHS)})",
     )
     ap.add_argument("--json", action="store_true", dest="as_json",
                     help="emit findings + per-site reports as JSON")

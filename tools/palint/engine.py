@@ -4,8 +4,7 @@ A rule is a class with a unique ``name``, a one-line ``summary``, and a
 ``check`` method yielding :class:`Finding` (a violation — fails the run)
 and/or :class:`Report` (informational data surfaced in ``--json``, e.g.
 per-``pallas_call`` VMEM estimates). Python rules get a parsed
-:class:`PyModule`; data rules (``bench-schema``) get raw file bytes;
-project rules run once against the repo root.
+:class:`PyModule`; project rules run once against the repo root.
 
 Per-line suppression::
 
@@ -110,12 +109,9 @@ class Rule:
 
     name: str = ""
     summary: str = ""
-    kind: str = "python"  # "python" | "data" | "project"
+    kind: str = "python"  # "python" | "project"
 
     def check(self, module: PyModule, ctx: Context) -> Iterable:
-        raise NotImplementedError
-
-    def check_data(self, path: str, rel: str, raw: bytes, ctx: Context) -> Iterable:
         raise NotImplementedError
 
     def check_project(self, ctx: Context) -> Iterable:
@@ -142,7 +138,7 @@ def all_rules() -> List[Rule]:
     return [_REGISTRY[k] for k in sorted(_REGISTRY)]
 
 
-DEFAULT_PATHS = ("src", "tests", "examples", "benchmarks", "tools")
+DEFAULT_PATHS = ("src", "tests", "examples", "tools")
 
 
 def _rel(path: str, root: str) -> str:
@@ -182,8 +178,7 @@ def run(
     ctx: Optional[Context] = None,
 ) -> Result:
     """Run every registered rule over ``paths`` (files or directories,
-    relative to ``root``). Defaults: :data:`DEFAULT_PATHS` plus the
-    repo-root ``BENCH_*.json`` benchmark records."""
+    relative to ``root``). Default: :data:`DEFAULT_PATHS`."""
     root = os.path.abspath(root or repo_root())
     ctx = ctx or Context(root=root)
     ctx.root = root
@@ -193,16 +188,6 @@ def run(
         p for p in DEFAULT_PATHS if os.path.exists(os.path.join(root, p))
     ]
     py_files = walk_files(scan, root=root, suffixes=(".py",))
-    bench_files = walk_files(scan, root=root, patterns=("BENCH_*.json",))
-    if explicit is None:
-        bench_files += walk_files(
-            sorted(
-                f for f in os.listdir(root)
-                if re.fullmatch(r"BENCH_.*\.json", f)
-            ),
-            root=root,
-        )
-    bench_files = sorted(dict.fromkeys(bench_files))
 
     rules = all_rules()
     findings: List[Finding] = []
@@ -233,24 +218,10 @@ def run(
             if rule.kind == "python":
                 emit(rule.check(module, ctx), module)
 
-    for path in bench_files:
-        rel = _rel(path, root)
-        try:
-            raw = open(path, "rb").read()
-        except OSError as e:
-            findings.append(Finding(
-                rule="bench-schema", path=rel, line=0,
-                message=f"unreadable: {e}",
-            ))
-            continue
-        for rule in rules:
-            if rule.kind == "data":
-                emit(rule.check_data(path, rel, raw, ctx))
-
     for rule in rules:
         if rule.kind == "project":
             emit(rule.check_project(ctx))
 
     findings.sort(key=lambda f: (f.path, f.line, f.col, f.rule))
     reports.sort(key=lambda r: (r.path, r.line, r.rule))
-    return Result(findings, reports, n_files=len(py_files) + len(bench_files))
+    return Result(findings, reports, n_files=len(py_files))

@@ -1,7 +1,6 @@
 # Importing this package registers every rule with the engine registry.
 from tools.palint.rules import (  # noqa: F401
     axis_name,
-    bench_schema,
     bytecode,
     compat_surface,
     jit_purity,
